@@ -27,6 +27,9 @@ whole ensemble).  What BENCH_4.json locks down:
 
 Counter reads sit behind ``jax.block_until_ready``: jitted calls return
 before the host callbacks run, so an eager read undercounts.
+
+The run is in float64 (``run_ensemble`` turns x64 on for its own scope
+only; importing this module leaves the process precision alone).
 """
 import json
 import sys
@@ -35,8 +38,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-jax.config.update("jax_enable_x64", True)
 
 from repro.core.implicit import odeint_implicit
 from repro.mem.model import tree_bytes
@@ -73,6 +74,11 @@ def _solve(u0, c, *, dt, n_steps, method, adjoint="pnode", offload=None,
 def run_ensemble(batch=1024, n_steps=30, train_steps=5, dt=0.01, lr=0.05,
                  seed=0):
     """Train the ensemble under a spill-forcing budget; return the record."""
+    with jax.enable_x64(True):
+        return _run_ensemble(batch, n_steps, train_steps, dt, lr, seed)
+
+
+def _run_ensemble(batch, n_steps, train_steps, dt, lr, seed):
     # 16 Newton iters: the stiffest sampled elements converge linearly
     # (GMRES inexactness) and need >12 to hit newton_tol across the batch
     solver_opts = dict(newton_iters=16, gmres_iters=5)

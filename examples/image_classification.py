@@ -10,25 +10,12 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.depth_ode import ODEBlock
 from repro.models.ode_nets import (classifier_apply, classifier_init,
-                                   conv_vf, softmax_xent)
+                                   conv_vf, make_classifier_step,
+                                   synthetic_cifar)
 from repro.optim.adamw import AdamW
-
-
-def synthetic_cifar(key, n, n_classes=10):
-    """Class-conditional Gaussian blobs in image space: learnable but
-    non-trivial (accuracy well above chance requires the conv features)."""
-    kl, kx = jax.random.split(key)
-    labels = jax.random.randint(kl, (n,), 0, n_classes)
-    base = jax.random.normal(
-        jax.random.PRNGKey(0), (n_classes, 8, 8, 3))  # fixed class templates
-    t = base[labels]
-    t = jax.image.resize(t, (n, 32, 32, 3), "nearest")
-    x = t + 0.6 * jax.random.normal(kx, (n, 32, 32, 3))
-    return x, labels
 
 
 def main():
@@ -48,21 +35,14 @@ def main():
     params = classifier_init(jax.random.PRNGKey(0), channels=args.channels)
     opt = AdamW(lr=2e-3, warmup_steps=10, total_steps=args.steps)
     state = opt.init(params)
-
-    def loss_fn(params, x, labels):
-        logits = classifier_apply(
-            params, x, odeint_fn=lambda vf, u, th: block(u, th))
-        return softmax_xent(logits, labels), logits
-
-    g_fn = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))
+    step_fn = make_classifier_step(block, opt)
 
     key = jax.random.PRNGKey(1)
     t0 = time.time()
     for step in range(args.steps):
         key, sub = jax.random.split(key)
         x, labels = synthetic_cifar(sub, args.batch)
-        (loss, logits), g = g_fn(params, x, labels)
-        params, state, _ = opt.update(g, state, params)
+        params, state, loss, logits, _ = step_fn(params, state, x, labels)
         if step % max(1, args.steps // 10) == 0:
             acc = float((logits.argmax(-1) == labels).mean())
             print(f"step {step:4d} loss {float(loss):.4f} acc {acc:.3f} "

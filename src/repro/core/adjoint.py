@@ -129,13 +129,10 @@ def _reject_vmap_offload(u0: PyTree, theta: PyTree, where: str) -> None:
     in the tracer stack (vmap(grad(...)): JVPTracers whose primals are
     BatchTracers), so unwrap nested tracers before testing.
     """
-    try:
-        from jax.interpreters.batching import BatchTracer
-    except ImportError:  # pragma: no cover - future jax moved it
-        return
+    from repro.mem.offload import is_batch_tracer  # deferred: import cycle
 
     def has_batch_tracer(x, depth=0) -> bool:
-        if isinstance(x, BatchTracer):
+        if is_batch_tracer(x):
             return True
         if isinstance(x, jax.core.Tracer) and depth < 8:
             return any(

@@ -103,11 +103,13 @@ def checkpointed_scan(layer_fn: LayerFn, u0: PyTree, stacked_params: PyTree,
 
 class ODEBlock:
     """Continuous-depth block: integrates du/dt = F(u, theta, t) with any
-    explicit method and any PNODE adjoint policy (shared weights over depth)."""
+    explicit method and any PNODE adjoint policy (shared weights over depth).
+    Further keywords (``offload``, ``fused_stages``, ...) go to ``odeint``
+    unchanged."""
 
     def __init__(self, vf, *, n_steps: int = 4, method: str = "rk4",
                  adjoint: str = "pnode", ncheck: int | None = None,
-                 t0: float = 0.0, t1: float = 1.0):
+                 t0: float = 0.0, t1: float = 1.0, **odeint_kw):
         self.vf = vf
         self.n_steps = n_steps
         self.method = method
@@ -115,8 +117,9 @@ class ODEBlock:
         self.ncheck = ncheck
         self.t0 = t0
         self.dt = (t1 - t0) / n_steps
+        self.odeint_kw = odeint_kw
 
     def __call__(self, u0: PyTree, theta: PyTree) -> PyTree:
         return odeint(self.vf, u0, theta, dt=self.dt, n_steps=self.n_steps,
                       t0=self.t0, method=self.method, adjoint=self.adjoint,
-                      ncheck=self.ncheck)
+                      ncheck=self.ncheck, **self.odeint_kw)

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import tree_util as jtu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -82,6 +81,6 @@ def pipeline_apply(layer_fn, params, x, *, mesh, n_micro: int,
         return outbuf.reshape((b,) + xg.shape[1:])
 
     pspecs = jtu.tree_map(lambda _: P(axis), params)
-    fn = shard_map(stage, mesh=mesh, in_specs=(pspecs, P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(stage, mesh=mesh, in_specs=(pspecs, P()),
+                       out_specs=P(), check_vma=False)
     return fn(params, x)

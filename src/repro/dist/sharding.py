@@ -32,8 +32,9 @@ used at most once per leaf.  Stacked-scan leaves (``blocks/scan/...``) never
 shard their leading unit dim — ``lax.scan`` slices it every step.
 
 ``constrain_batch`` / ``constrain_dims`` are the in-graph counterparts: they
-apply ``lax.with_sharding_constraint`` under an active mesh and are exact
-no-ops outside one, so model code stays mesh-agnostic.
+apply ``lax.with_sharding_constraint`` under a mesh set with
+``jax.set_mesh`` (Auto axes, see ``launch.mesh``) and are exact no-ops
+outside one, so model code stays mesh-agnostic.
 """
 from __future__ import annotations
 
@@ -55,28 +56,10 @@ _REPLICATE_MAX = 65536
 # mesh helpers
 # ---------------------------------------------------------------------------
 
-_warned_no_mesh_api = False
-
-
 def _current_mesh():
-    """The ambient physical mesh (``with mesh:``), or None outside one."""
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # pragma: no cover - jax internals moved
-        # warn loudly ONCE instead of silently degrading every sharding
-        # constraint to a no-op (which would compile models fully replicated)
-        global _warned_no_mesh_api
-        if not _warned_no_mesh_api:
-            _warned_no_mesh_api = True
-            import warnings
-            warnings.warn(
-                "repro.dist.sharding could not read the ambient mesh from "
-                "jax internals; all sharding constraints are no-ops. "
-                "Update _current_mesh for this jax version.")
-    return None
+    """The ambient mesh set with ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def _axis_size(mesh, name: str) -> int:

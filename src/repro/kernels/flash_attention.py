@@ -110,7 +110,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
 
     kernel = functools.partial(
         _flash_kernel, scale=1.0 / (dh ** 0.5), block_q=block_q,

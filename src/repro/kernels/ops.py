@@ -1,11 +1,9 @@
 """jit'd public wrappers for the Pallas kernels (model-facing layouts) plus
 the fused RK stage-combine kernel used by the adjoint hot path.
 
-Note (interpret-mode CPU caveat, same as flash_attention/rwkv6): on
-non-TPU backends every kernel here runs through the Pallas interpreter, so
-the fusion is semantic (one kernel call, one output buffer, accumulation
-order fixed inside the kernel) rather than a measured VMEM win; real-TPU
-validation is an open ROADMAP item.
+Every kernel here (and in flash_attention/rwkv6_scan) runs through the
+Pallas interpreter on the CPU backend, which the tests use, and compiles
+with Mosaic on every other backend.
 """
 from __future__ import annotations
 
@@ -14,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rwkv6_scan import rwkv6_chunked_bhsd
@@ -68,6 +67,16 @@ def _lincomb_kernel_scaled(*refs, weights, base_coeff):
     out_ref[...] = acc
 
 
+#: leaves above one block run on a lane-dense ``(rows, 128)`` view in
+#: blocks of 1024 rows: 512 KiB per f32 operand buffer, so even dopri5's
+#: seven terms plus base and output, double-buffered, stay inside the
+#: default scoped VMEM.  A leaf that fits one block runs gridless on its
+#: flat vector (also the form whose XLA:CPU interpretation is bitwise
+#: equal to the unfused chain).
+_LANES = 128
+_BLOCK_ROWS = 1024
+
+
 def fused_lincomb(base: jax.Array, terms, weights, scale=None,
                   base_coeff: float | None = None, *,
                   interpret: bool | None = None) -> jax.Array:
@@ -76,29 +85,60 @@ def fused_lincomb(base: jax.Array, terms, weights, scale=None,
     ``weights`` are trace-time floats (Butcher-tableau entries); ``scale``
     is the step size h — a Python float (fixed-step: folded into the
     coefficients at trace time) or a traced scalar (adaptive: passed as a
-    kernel operand).  ``base_coeff=None`` means the base enters unscaled
-    (the RK state-update form); a float (including 0.0) multiplies it
-    first (the adjoint ``v_i = b_i*lam + ...`` form).  Zero weights must be
-    dropped by the caller (to mirror the unfused chain's trace-time skip).
+    kernel operand in SMEM).  ``base_coeff=None`` means the base enters
+    unscaled (the RK state-update form); a float (including 0.0)
+    multiplies it first (the adjoint ``v_i = b_i*lam + ...`` form).  Zero
+    weights must be dropped by the caller (to mirror the unfused chain's
+    trace-time skip).
+
+    A leaf larger than one ``(1024, 128)`` block is viewed as rows of 128
+    lanes, its tail zero-padded to whole blocks, and the kernel runs over
+    a 1-D grid of row blocks; the padding is sliced off the result.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    shape = base.shape
-    flat = base.reshape(-1)  # interpret-mode pallas wants >= 1-D operands
-    fterms = [t.reshape(-1) for t in terms]
-    out_sds = jax.ShapeDtypeStruct(flat.shape, flat.dtype)
+        interpret = jax.default_backend() == "cpu"
+    shape, n = base.shape, base.size
+    rows = -(-n // _LANES)
+    if rows <= _BLOCK_ROWS:
+        out_shape, grid = (n,), ()
+        blk = pl.BlockSpec(memory_space=pltpu.VMEM)
+        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+        def view(x):
+            return x.reshape(-1)
+    else:
+        rows_p = -(-rows // _BLOCK_ROWS) * _BLOCK_ROWS
+        out_shape, grid = (rows_p, _LANES), (rows_p // _BLOCK_ROWS,)
+        # int32 block indices: a Python 0 (or the default index map) is
+        # int64 under x64, which Mosaic cannot lower
+        blk = pl.BlockSpec((_BLOCK_ROWS, _LANES),
+                           lambda i: (i, jnp.int32(0)))
+        smem = pl.BlockSpec(index_map=lambda i: (jnp.int32(0),),
+                            memory_space=pltpu.SMEM)
+
+        def view(x):
+            x = jnp.pad(x.reshape(-1), (0, rows_p * _LANES - n))
+            return x.reshape(rows_p, _LANES)
+
+    call = functools.partial(
+        pl.pallas_call, out_shape=jax.ShapeDtypeStruct(out_shape, base.dtype),
+        grid=grid, out_specs=blk, interpret=interpret)
+    vterms = [view(t) for t in terms]
     if scale is None or isinstance(scale, (int, float)):
         coeffs = [w if scale is None else float(scale) * w for w in weights]
         kern = functools.partial(_lincomb_kernel_static, coeffs=coeffs,
                                  base_coeff=base_coeff)
-        out = pl.pallas_call(kern, out_shape=out_sds,
-                             interpret=interpret)(flat, *fterms)
+        out = call(kern, in_specs=[blk] * (1 + len(vterms)))(view(base),
+                                                             *vterms)
     else:
         kern = functools.partial(_lincomb_kernel_scaled, weights=list(weights),
                                  base_coeff=base_coeff)
-        h_op = jnp.asarray(scale, flat.dtype).reshape(1)
-        out = pl.pallas_call(kern, out_shape=out_sds,
-                             interpret=interpret)(flat, h_op, *fterms)
+        h_op = jnp.asarray(scale, base.dtype).reshape(1)
+        out = call(kern, in_specs=[blk, smem] + [blk] * len(vterms))(
+            view(base), h_op, *vterms)
+    out = out.reshape(-1)
+    if out.size != n:
+        out = out[:n]
     return out.reshape(shape)
 
 

@@ -82,7 +82,7 @@ def rwkv6_chunked_bhsd(r: jax.Array, k: jax.Array, v: jax.Array,
     assert s % chunk == 0, (s, chunk)
     nc = s // chunk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
 
     kernel = functools.partial(_rwkv6_kernel, chunk=chunk)
     out, sfin = pl.pallas_call(

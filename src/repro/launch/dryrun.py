@@ -204,7 +204,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, accum: int = 1,
         t0 = time.time()
         fn, args, in_sh, cfg, cell = build_cell(arch, shape, mesh, accum,
                                                 remat, attn_impl)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0

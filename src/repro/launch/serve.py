@@ -34,7 +34,7 @@ import numpy as np
 from repro.configs.base import ShapeCell, reduced
 from repro.configs.registry import get_arch
 from repro.data.pipeline import SyntheticLM
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import enable_compile_cache, make_host_mesh
 from repro.obs import MetricsSink, StructuredLogger
 from repro.serve import LMEngine
 
@@ -125,6 +125,7 @@ def main():
                     help="write structured serve stats as JSONL to PATH")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced(get_arch(args.arch))
     sink = MetricsSink(args.metrics) if args.metrics else None
     tokens, stats = serve(cfg, batch=args.batch, prompt_len=args.prompt_len,

@@ -1,11 +1,15 @@
 """End-to-end LM training driver: mesh + sharding + synthetic data + AdamW
 + fault tolerance (watchdog, straggler detection, checkpoint-restart).
 
-Runs any assigned arch (full config on the production mesh via --production,
-reduced config on host devices by default so CPU runs finish):
+Runs any assigned arch: a reduced config on the devices present by default
+(so CPU runs finish), the full config on the devices present with --full
+(e.g. SmolLM-135M on one TPU v5e), or the full config on the 16x16
+production mesh with --production:
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
-      --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+      --steps 200 --batch 8 --seq 128 --ckpt-dir ckpt
+  PYTHONPATH=src python -m repro.launch.train --arch smollm-135m --full \
+      --steps 3 --batch 8 --seq 1024
 
 Deterministic restart: the data pipeline is keyed by step and the checkpoint
 carries (params, opt_state, step), so rerunning with the same --ckpt-dir
@@ -28,7 +32,8 @@ from repro.configs.registry import get_arch
 from repro.data.pipeline import SyntheticLM
 from repro.dist import sharding as shd
 from repro.ft import StragglerDetector, TrainSupervisor
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.mesh import (enable_compile_cache, make_host_mesh,
+                               make_production_mesh)
 from repro.launch.steps import init_compress_state, make_train_step
 from repro.models import lm
 from repro.obs import MetricsSink, StructuredLogger
@@ -36,15 +41,12 @@ from repro.optim.adamw import AdamW
 
 
 def _compiled_peak_bytes(step_fn, *concrete_args):
-    """Best-effort measured peak of the compiled train step
+    """Measured peak of the compiled train step
     (``launch.hlo_cost.peak_live_bytes`` — the same metric the byte-budget
-    planner verifies against).  None if lowering text is unavailable."""
-    try:
-        from repro.launch.hlo_cost import peak_live_bytes
-        compiled = step_fn.lower(*concrete_args).compile()
-        return int(peak_live_bytes(compiled.as_text()))
-    except Exception:
-        return None
+    planner verifies against)."""
+    from repro.launch.hlo_cost import peak_live_bytes
+    compiled = step_fn.lower(*concrete_args).compile()
+    return int(peak_live_bytes(compiled.as_text()))
 
 
 def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
@@ -56,7 +58,9 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
           predicted_peak_bytes: int | None = None,
           fault_plan=None, sentinel: bool = True,
           sentinel_bad_steps: int = 3, max_rollbacks: int = 2) -> dict:
-    """Returns {"losses": [...], "resumed_from": step|None, ...}.
+    """Returns {"losses": [...], "grad_norms": [...], "resumed_from":
+    step|None, ...}; the i-th loss and pre-clip global gradient norm are
+    those of committed step ``start+i``.
 
     ``compress`` wires optim/compress.py gradient compression into the
     production step (flag-gated, default off; see launch/steps.py).
@@ -95,7 +99,7 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
                 grad_dtype=grad_dtype)
     pipe = SyntheticLM(cfg, cell, seed=seed)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params_shape = jax.eval_shape(
             lambda: lm.init_params(cfg, jax.random.PRNGKey(seed)))
         pspecs = shd.param_specs(cfg, params_shape, mesh)
@@ -173,7 +177,7 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
             # must still log the compile record — with drift=null — not
             # die on the division below
             drift = None
-            if measured_peak is not None and predicted_peak_bytes:
+            if predicted_peak_bytes:
                 # the planner prices live *activations*; the compiled peak
                 # also holds params/opt-state/batch, so fold those in
                 from repro.mem.model import tree_bytes
@@ -196,10 +200,12 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
         detector = StragglerDetector()
         stragglers: list[int] = []
         loss_by_step: dict[int, float] = {}
+        norm_by_step: dict[int, float] = {}
         skipped = 0
         rollbacks = 0
         consec_bad = 0
         preempted = False
+        saved_at = None
         stop = {"sig": False}
         prev_handler = None
         try:  # SIGTERM = finish the in-flight step, checkpoint, drain
@@ -283,7 +289,7 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
                             rollbacks += 1
                             consec_bad = 0
                             for s in [s for s in loss_by_step if s >= rstep]:
-                                del loss_by_step[s]
+                                del loss_by_step[s], norm_by_step[s]
                             slog.log("train.rollback",
                                      f"[train] rolled back to step {rstep} "
                                      f"after {sentinel_bad_steps} "
@@ -298,17 +304,17 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
                     consec_bad = 0
                     loss = float(m["loss"])
                     loss_by_step[step] = loss
+                    norm_by_step[step] = float(m["grad_norm"])
                     if sink is not None:
-                        gn = m.get("grad_norm")
                         slog.metric("train.step", step=step, loss=loss,
-                                    grad_norm=(None if gn is None
-                                               else float(gn)),
+                                    grad_norm=norm_by_step[step],
                                     step_ms=dt * 1e3)
                     if step % log_every == 0 or step == steps - 1:
                         log_fn(f"[train] step {step:5d} loss {loss:.4f} "
                                f"({dt*1e3:.0f} ms)")
                     if mgr and (step + 1) % ckpt_every == 0:
                         mgr.save(step + 1, ckpt_tree())
+                        saved_at = step + 1
                     step += 1
                     if want_preempt:
                         fault_plan.note("train.preempt", step)
@@ -323,12 +329,16 @@ def train(cfg: ModelConfig, cell: ShapeCell, *, steps: int, mesh=None,
         if mgr:
             # `step` is the committed progress (next step to run): the
             # final checkpoint lands there whether the loop completed or a
-            # preemption broke out early, and wait() drains every pending
-            # async commit before we return
-            mgr.save(step, ckpt_tree())
+            # preemption broke out early (unless the loop just saved that
+            # step: two async commits of one step race on its directory),
+            # and wait() drains every pending async commit before we return
+            if saved_at != step:
+                mgr.save(step, ckpt_tree())
             mgr.wait()
         losses = [loss_by_step[s] for s in sorted(loss_by_step)]
-    return {"losses": losses, "resumed_from": start_step or None,
+        grad_norms = [norm_by_step[s] for s in sorted(norm_by_step)]
+    return {"losses": losses, "grad_norms": grad_norms,
+            "resumed_from": start_step or None,
             "stragglers": stragglers, "params": params,
             "skipped_steps": skipped, "rollbacks": rollbacks,
             "preempted": preempted}
@@ -353,9 +363,13 @@ def main():
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--production", action="store_true",
-                    help="full config on the 16x16 production mesh "
-                         "(requires real devices)")
+    mode = ap.add_mutually_exclusive_group()
+    mode.add_argument("--full", action="store_true",
+                      help="full config on the devices present "
+                           "(default: the reduced smoke config)")
+    mode.add_argument("--production", action="store_true",
+                      help="full config on the 16x16 production mesh "
+                           "(requires 256 real devices)")
     ap.add_argument("--remat", default=None)
     ap.add_argument("--grad-dtype", default=None)
     ap.add_argument("--compress", default="none",
@@ -380,9 +394,12 @@ def main():
                          "rollbacks (default 2)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     full = get_arch(args.arch)
     if args.production:
         cfg, mesh = full, make_production_mesh()
+    elif args.full:
+        cfg, mesh = full, make_host_mesh()
     else:
         cfg, mesh = reduced(full), make_host_mesh()
     if args.remat:

@@ -8,15 +8,17 @@ as ``custom_vjp`` residuals.  Four tiers:
            pytree — exactly the seed behavior (XLA keeps them in device
            memory for the whole fwd->bwd window).
   host     checkpoints are moved to the backend's pinned-host memory space
-           with ``jax.device_put(x, TransferToMemoryKind("pinned_host"))``
-           at put time and brought back at get time; the residual pytree
-           carries host-resident arrays, so device-live memory between the
-           sweeps is O(working set).  Sharded arrays keep their layout: a
+           with ``jax.device_put(x, jax.memory.Space.Host)`` at put time and
+           brought back at get time; the residual pytree carries
+           host-resident arrays, so device-live memory between the sweeps
+           is O(working set).  Sharded arrays keep their layout: a
            memory-kind transfer preserves the NamedSharding, so each device
-           spills its own shard.  On backends without a pinned_host space
-           (XLA:CPU in this container exposes only unpinned_host) the tier
-           degrades to ``device`` and records ``effective_tier`` so callers
-           and tests can see the downgrade.
+           spills its own shard.  A backend without a pinned_host space
+           cannot hold this tier: ``HostStore`` raises there instead of
+           keeping the checkpoints on the device.  Inside jit, JAX lowers
+           the transfer only for TPU/GPU (``annotate_device_placement``);
+           on XLA:CPU it is the identity, so there the tier compiles to
+           the ``device`` program.
   spill    checkpoints leave the XLA program entirely through a
            token-threaded ``jax.pure_callback`` into a host-side numpy dict.
            The residual is one f32 scalar (the ordering token), so the
@@ -230,6 +232,12 @@ _CB_PAYLOAD_CAP = 96 * 1024
 _DISK_PREFIX = "repro_spill_"
 
 
+def is_batch_tracer(x) -> bool:
+    """True for a vmap tracer: the only JAX tracer carrying ``batch_dim``
+    (jax no longer exports its class)."""
+    return isinstance(x, jax.core.Tracer) and hasattr(x, "batch_dim")
+
+
 def batch_scale(tree: PyTree) -> int:
     """Product of mapped-axis sizes riding the leaves of ``tree`` — the
     factor by which vmap multiplies every callback payload.
@@ -239,16 +247,11 @@ def batch_scale(tree: PyTree) -> int:
     ``core.adjoint._reject_vmap_offload``): ``custom_vjp`` forwards are
     retraced at *logical* shapes, so by the time ``write_batch`` runs the
     batch axes cannot be recovered from its arguments."""
-    try:
-        from jax.interpreters.batching import BatchTracer
-    except ImportError:  # pragma: no cover - future jax moved it
-        return 1
-
     def scale(x) -> int:
         s, y, depth = 1, x, 0
         while isinstance(y, jax.core.Tracer) and depth < 8:
-            if isinstance(y, BatchTracer):
-                bd = getattr(y, "batch_dim", None)
+            if is_batch_tracer(y):
+                bd = y.batch_dim
                 if isinstance(bd, int):
                     s *= int(np.shape(y.val)[bd])
                 y = y.val
@@ -363,19 +366,12 @@ def default_segment(n_steps: int) -> int:
 
 
 def host_memory_kind() -> Optional[str]:
-    """The backend's off-device host memory space, or None if unavailable."""
-    try:
-        kinds = [m.kind for m in jax.devices()[0].addressable_memories()]
-    except Exception:  # pragma: no cover - very old jaxlib
-        return None
-    default = None
-    try:
-        default = jax.devices()[0].default_memory().kind
-    except Exception:  # pragma: no cover
-        pass
-    for kind in ("pinned_host", "unpinned_host"):
-        if kind in kinds and kind != default:
-            return kind
+    """``"pinned_host"`` when the default device can address pinned host
+    memory (what ``jax.memory.Space.Host`` maps to), else None."""
+    dev = jax.devices()[0]
+    kinds = {m.kind for m in dev.addressable_memories()}
+    if "pinned_host" in kinds and dev.default_memory().kind != "pinned_host":
+        return "pinned_host"
     return None
 
 
@@ -579,32 +575,23 @@ class DeviceStore(CheckpointStore):
 
 
 class HostStore(CheckpointStore):
-    """Pinned-host residuals via memory-kind transfer (degrades to device)."""
+    """Pinned-host residuals via memory-kind transfer."""
 
     tier = "host"
 
     def __init__(self):
         super().__init__()
-        self._kind = host_memory_kind()
-        self.effective_tier = "host" if self._kind else "device"
-
-    def _transfer(self, tree: PyTree, kind: str) -> PyTree:
-        try:
-            from jax._src.sharding_impls import TransferToMemoryKind
-        except ImportError:  # pragma: no cover - newer jax moved it
-            from jax.sharding import TransferToMemoryKind  # type: ignore
-        return jtu.tree_map(
-            lambda x: jax.device_put(x, TransferToMemoryKind(kind)), tree)
+        if host_memory_kind() is None:
+            raise RuntimeError(
+                "offload='host' needs a pinned_host memory space, which "
+                f"{jax.devices()[0].device_kind!r} does not expose; use "
+                "offload='spill' or 'disk'")
 
     def _to_store(self, tree: PyTree) -> PyTree:
-        if self._kind is None:
-            return tree
-        return self._transfer(tree, self._kind)
+        return jax.device_put(tree, jax.memory.Space.Host)
 
     def _from_store(self, tree: PyTree) -> PyTree:
-        if self._kind is None:
-            return tree
-        return self._transfer(tree, "device")
+        return jax.device_put(tree, jax.memory.Space.Device)
 
 
 class SpillStore(CheckpointStore):

@@ -166,3 +166,40 @@ def softmax_xent(logits, labels) -> jax.Array:
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
     return (logz - gold).mean()
+
+
+def synthetic_cifar(key, n, n_classes=10):
+    """CIFAR-10-shaped stand-in (the dataset is not available offline):
+    class-conditional Gaussian blobs in image space, (n, 32, 32, 3) images
+    and int labels.  Learnable but non-trivial (accuracy well above chance
+    requires the conv features)."""
+    kl, kx = jax.random.split(key)
+    labels = jax.random.randint(kl, (n,), 0, n_classes)
+    base = jax.random.normal(
+        jax.random.PRNGKey(0), (n_classes, 8, 8, 3))  # fixed class templates
+    t = base[labels]
+    t = jax.image.resize(t, (n, 32, 32, 3), "nearest")
+    x = t + 0.6 * jax.random.normal(kx, (n, 32, 32, 3))
+    return x, labels
+
+
+def make_classifier_step(block, opt):
+    """One optimizer step of the §5.1 classifier whose ODE block is
+    ``block`` (a ``core.depth_ode.ODEBlock`` over ``conv_vf``).
+
+    Returns the jitted ``step(params, opt_state, images, labels) ->
+    (params, opt_state, loss, logits, grads)``; ``grads`` are the
+    gradients the update was taken from."""
+    def loss_fn(params, images, labels):
+        logits = classifier_apply(
+            params, images, odeint_fn=lambda vf, u, th: block(u, th))
+        return softmax_xent(logits, labels), logits
+
+    @jax.jit
+    def step(params, opt_state, images, labels):
+        (loss, logits), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, images, labels)
+        params, opt_state, _ = opt.update(grads, opt_state, params)
+        return params, opt_state, loss, logits, grads
+
+    return step

@@ -162,8 +162,7 @@ def _shard_attention_inputs(q, k, v):
     bspec = ba if (ba and q.shape[0] % nb == 0 and q.shape[0] >= nb) else None
 
     def cons(x, spec):
-        sh = NamedSharding(mesh, spec) if hasattr(mesh, "devices") else spec
-        return _jax.lax.with_sharding_constraint(x, sh)
+        return _jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
     h, hkv = q.shape[2], k.shape[2]
     if h % n == 0 and hkv % n == 0:

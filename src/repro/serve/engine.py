@@ -460,7 +460,7 @@ class LMEngine:
         self.pos0 = self.prompt_len + (
             cfg.n_patches if cfg.frontend == "vision_stub" else 0)
 
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             if params is None:
                 params = jax.jit(lambda k: lm_mod.init_params(cfg, k))(
                     jax.random.PRNGKey(self.seed))
@@ -545,7 +545,7 @@ class LMEngine:
             prompt[k] = jnp.asarray(stack)
         compile_ = not self.call_log  # first prefill pays the compile
         t_start = time.time()
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             state, logits = self._prefill_fn(self.params, prompt)
             jax.block_until_ready(logits)
         wall = time.time() - t_start
@@ -582,7 +582,7 @@ class LMEngine:
         compile_ = self._decode_calls == 0
         armed = self.fault_plan is not None
         t_start = time.time()
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             for _ in range(k):
                 i = len(wave.emitted) - 1  # decode steps taken so far
                 logits, wave.state = self._decode_fn(

@@ -377,7 +377,7 @@ def _serve_f32(request):
     if "serve" not in request.node.name:
         yield
         return
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         yield
 
 

@@ -5,16 +5,16 @@ small-mesh dry-run)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs.base import ShapeCell, reduced
 from repro.configs.registry import get_arch
 from repro.dist import sharding as shd
 from repro.models import lm
-from tests.util import abstract_mesh, run_with_devices
+from tests.util import run_with_devices
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(arch, mesh=MESH):
@@ -149,14 +149,15 @@ from repro.configs.base import ShapeCell, reduced
 from repro.configs.registry import get_arch
 from repro.data.pipeline import SyntheticLM
 from repro.dist import sharding as shd
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import lm
 from repro.optim.adamw import AdamW
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(model_axis=2)
 cfg = reduced(get_arch("smollm-135m"), d_model=64, n_heads=4, n_kv_heads=2)
 cell = ShapeCell("t", 32, 8, "train")
-with mesh:
+with jax.set_mesh(mesh):
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     pspecs = shd.param_specs(cfg, jax.eval_shape(lambda: params), mesh)
     pshard = shd.to_shardings(pspecs, mesh)
@@ -203,16 +204,15 @@ print("PIPELINE_OK", err)
 def test_compressed_psum_8dev():
     out = run_with_devices("""
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.optim.compress import compressed_psum
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 128)) * 0.01
 true_sum = g.sum(axis=0)
 for scheme, tol in [("none", 1e-6), ("bf16", 2e-2), ("int8", 5e-2)]:
-    fn = shard_map(lambda gg: compressed_psum(gg, "pod", scheme),
-                   mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
-                   check_rep=False)
+    fn = jax.shard_map(lambda gg: compressed_psum(gg, "pod", scheme),
+                       mesh=mesh, in_specs=(P("pod"),), out_specs=P("pod"),
+                       check_vma=False)
     out = fn(g)[0]
     rel = float(jnp.linalg.norm(out - true_sum) / jnp.linalg.norm(true_sum))
     assert rel < tol, (scheme, rel)
@@ -230,15 +230,14 @@ from repro.launch.dryrun import build_cell, collective_bytes
 from repro.configs.base import ShapeCell
 import repro.configs.base as base
 import repro.launch.dryrun as dr
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model_axis=4)
 fn, args, in_sh, cfg, cell = dr.build_cell("smollm-135m", "train_4k", mesh)
-with mesh:
+with jax.set_mesh(mesh):
     lowered = jax.jit(fn, in_shardings=in_sh).lower(*args)
     compiled = lowered.compile()
 mem = compiled.memory_analysis()
 cost = compiled.cost_analysis()
-if isinstance(cost, list):  # jax<=0.4.x returns [dict]
-    cost = cost[0]
 coll = collective_bytes(compiled.as_text())
 assert coll["total"] > 0
 assert float(cost.get("flops", 0)) > 0

@@ -116,7 +116,8 @@ def test_fused_with_spill_offload_composes():
 # fused_lincomb kernel vs oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape", [(7,), (4, 5), (2, 3, 4)])
+# (3, 50000) exceeds one (1024, 128) block: the gridded, tail-padded view
+@pytest.mark.parametrize("shape", [(7,), (4, 5), (2, 3, 4), (3, 50000)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_lincomb_matches_oracle(shape, dtype):
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
@@ -313,7 +314,8 @@ def test_vmap_offload_raises_clear_error():
     us = jnp.stack([u0, u0 + 0.1])
     with pytest.raises(NotImplementedError, match="offload='device'"):
         jax.vmap(lambda u: odeint(f, u, th, dt=DT, n_steps=N_STEPS,
-                                  adjoint="pnode", offload="spill"))(us)
+                                  adjoint="revolve", ncheck=3,
+                                  offload="spill"))(us)
     with pytest.raises(NotImplementedError, match="offload='device'"):
         jax.vmap(lambda u: odeint_adaptive(
             f, u, th, t0=0.0, t1=0.5, offload="spill")[0])(us)
@@ -321,15 +323,16 @@ def test_vmap_offload_raises_clear_error():
 
 def test_vmap_of_grad_offload_raises_clear_error():
     """vmap(grad(...)) wraps the batch axis inside JVP tracers — the guard
-    must unwrap them, or the host dict would alias per-example checkpoints
-    and silently return wrong gradients."""
+    must unwrap them, or the slot-addressed host dict would alias
+    per-example checkpoints and silently return wrong gradients."""
     f = _vf()
     u0, th = _problem()
     us = jnp.stack([u0, u0 + 0.1])
 
     def loss(u):
         return jnp.sum(odeint(f, u, th, dt=DT, n_steps=N_STEPS,
-                              adjoint="pnode", offload="spill") ** 2)
+                              adjoint="revolve", ncheck=3,
+                              offload="spill") ** 2)
 
     with pytest.raises(NotImplementedError, match="offload='device'"):
         jax.vmap(jax.grad(loss))(us)

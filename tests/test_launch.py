@@ -35,6 +35,30 @@ def test_train_with_grad_compression(scheme):
     np.testing.assert_allclose(base, comp, rtol=5e-2)
 
 
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The CLIs' persistent compile cache: JAX_COMPILATION_CACHE_DIR when
+    set (JAX reads it itself, so nothing is set in code), else one fixed
+    directory at the checkout's root."""
+    from repro.launch.mesh import DEFAULT_COMPILE_CACHE, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = enable_compile_cache()
+        if from_env:
+            assert path == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert path == str(DEFAULT_COMPILE_CACHE)
+            assert jax.config.jax_compilation_cache_dir == path
+            assert (DEFAULT_COMPILE_CACHE.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_train_rejects_unknown_compression():
     from repro.launch.steps import make_train_step
     from repro.optim.adamw import AdamW

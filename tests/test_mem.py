@@ -18,6 +18,7 @@ from repro.mem import (DeviceStore, HostStore, SpillStore, candidate_costs,
                        host_memory_kind, measure_reverse_cost,
                        plan_depth_remat, plan_odeint, policy_cost,
                        tree_bytes)
+from repro.mem import offload as offload_mod
 
 jax.config.update("jax_enable_x64", True)
 
@@ -76,7 +77,7 @@ def test_spill_grads_bitwise_identical(policy, kw):
 @pytest.mark.parametrize("policy,kw", [("revolve", {"ncheck": 3}),
                                        ("revolve2", {"ncheck": 2})])
 def test_host_offload_grads_bitwise_identical(policy, kw):
-    """pinned-host tier (degrades to device on XLA:CPU, still exact)."""
+    """pinned-host tier (a memory-kind transfer; exact)."""
     _assert_bitwise(_grads(policy, offload="host", **kw),
                     _grads(policy, **kw))
 
@@ -109,11 +110,15 @@ def test_adaptive_spill_grads_bitwise_identical():
     _assert_bitwise(gfn("spill"), gfn(None))
 
 
-def test_host_store_degrades_on_cpu_and_reports():
-    st = HostStore()
-    assert st.effective_tier in ("host", "device")
-    if host_memory_kind() is None:
-        assert st.effective_tier == "device"
+def test_host_store_degrades_on_cpu_and_reports(monkeypatch):
+    """The host tier runs as ``host`` where pinned host memory exists
+    (XLA:CPU exposes it too) and raises where it does not, instead of
+    quietly keeping the checkpoints on the device."""
+    assert host_memory_kind() == "pinned_host"
+    assert HostStore().effective_tier == "host"
+    monkeypatch.setattr(offload_mod, "host_memory_kind", lambda: None)
+    with pytest.raises(RuntimeError, match="pinned_host"):
+        HostStore()
 
 
 def test_spill_store_roundtrip_and_free():

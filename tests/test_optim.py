@@ -108,7 +108,6 @@ def test_int8_quantization_range():
 def test_compressed_psum_matches_uncompressed():
     """On a size-1 axis, every scheme must be (near-)identity; exercised with
     a real multi-axis psum in the multi-device subprocess test."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compress import compressed_psum
 
@@ -116,9 +115,9 @@ def test_compressed_psum_matches_uncompressed():
     g = {"w": jax.random.normal(jax.random.PRNGKey(0), (64,))}
 
     for scheme in ("none", "bf16", "int8"):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda gg: compressed_psum(gg, "pod", scheme), mesh=mesh,
-            in_specs=(P(),), out_specs=P(), check_rep=False)
+            in_specs=(P(),), out_specs=P(), check_vma=False)
         out = fn(g)
         tol = {"none": 1e-7, "bf16": 1e-2, "int8": 3e-2}[scheme]
         np.testing.assert_allclose(np.asarray(out["w"]),

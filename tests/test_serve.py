@@ -34,7 +34,7 @@ def _f32_regime():
     # the serve stack targets the f32 regime; other test modules flip the
     # global x64 flag at import (collection order is alphabetical), so pin
     # it off for this whole module — module fixtures included
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         yield
 
 

@@ -11,15 +11,6 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[1]
 
 
-def abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions: new jax takes (sizes, names),
-    jax 0.4.x takes a ((name, size), ...) shape tuple."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
 PREAMBLE = """\
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
