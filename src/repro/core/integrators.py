@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax import tree_util as jtu
 
 from repro.core.tableaus import ButcherTableau, get_tableau
+from repro.obs.profile import scope
 
 PyTree = Any
 VectorField = Callable[[PyTree, PyTree, jax.Array], PyTree]
@@ -136,7 +137,8 @@ def rk_stages(f: VectorField, tab: ButcherTableau, u: PyTree, theta: PyTree,
         pairs = [(float(tab.a[i, j]), ks[j]) for j in range(i)
                  if float(tab.a[i, j]) != 0.0]
         xi = tree_stage_lincomb(u, pairs, scale=h, fused=fused)
-        ks.append(f(xi, theta, t + float(tab.c[i]) * h))
+        with scope("vf"):
+            ks.append(f(xi, theta, t + float(tab.c[i]) * h))
     return ks
 
 
@@ -168,6 +170,16 @@ def rk_stage_inputs(tab: ButcherTableau, u: PyTree, stages: PyTree, h,
                  if float(tab.a[i, j]) != 0.0]
         xs.append(tree_stage_lincomb(u, pairs, scale=h, fused=fused))
     return xs
+
+
+def _vf_at(f: VectorField, t):
+    """``f`` at time ``t`` under the ``obs:vf`` scope, so that the ops of
+    its linearisation carry ``jvp(obs:vf)`` and their transposes
+    ``transpose(jvp(obs:vf))`` in their name stack."""
+    def f_t(u, theta):
+        with scope("vf"):
+            return f(u, theta, t)
+    return f_t
 
 
 def rk_adjoint_step(f: VectorField, tab: ButcherTableau, u: PyTree,
@@ -203,7 +215,7 @@ def rk_adjoint_step(f: VectorField, tab: ButcherTableau, u: PyTree,
         vi = tree_stage_lincomb(lam, pairs, base_coeff=float(tab.b[i]),
                                 fused=fused)
         ti = t + float(tab.c[i]) * h
-        _, vjp_fn = jax.vjp(lambda uu, th: f(uu, th, ti), xs[i], theta)
+        _, vjp_fn = jax.vjp(_vf_at(f, ti), xs[i], theta)
         wi, gi = vjp_fn(tree_scale(h, vi))
         ws[i] = wi
         lam_prev = tree_add(lam_prev, wi)

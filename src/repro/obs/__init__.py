@@ -23,7 +23,6 @@ from repro.obs.registry import (DEFAULT_REGISTRY, FevalCounter, JitCounter,
                                 MetricsRegistry, default_registry)
 from repro.obs.sink import MetricsSink, StructuredLogger, read_jsonl
 from repro.obs.trace import FlightRecorder, TraceEvent
-from repro.obs.trace_export import export_chrome_trace, to_chrome_trace
 from repro.obs.profile import host_annotation, scope
 
 __all__ = [
@@ -32,6 +31,5 @@ __all__ = [
     "default_registry",
     "MetricsSink", "StructuredLogger", "read_jsonl",
     "FlightRecorder", "TraceEvent",
-    "export_chrome_trace", "to_chrome_trace",
     "host_annotation", "scope",
 ]

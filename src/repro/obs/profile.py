@@ -14,12 +14,9 @@ Two mechanisms, matched to where code runs:
     ``jax.profiler.TraceAnnotation`` for *host* code: wraps the body of a
     spill-store callback (or any host-side work) in a named profiler
     activity so the time XLA spends blocked on host I/O is attributed in
-    the trace viewer.  Degrades to a no-op context manager when the
-    profiler API is unavailable.
+    the trace viewer.
 """
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
@@ -32,9 +29,5 @@ def scope(name: str):
 
 
 def host_annotation(name: str):
-    """Profiler annotation for host-callback bodies; no-op if the
-    profiler API is missing."""
-    ta = getattr(jax.profiler, "TraceAnnotation", None)
-    if ta is None:
-        return contextlib.nullcontext()
-    return ta(f"{PREFIX}:{name}")
+    """Profiler annotation for host-callback bodies."""
+    return jax.profiler.TraceAnnotation(f"{PREFIX}:{name}")

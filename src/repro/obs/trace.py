@@ -74,8 +74,8 @@ class TraceEvent:
     seq: int
     runtime: bool  # True: emitted during execution; False: during tracing
     #: host wall clock at record time (time.time()).  Host-side metadata
-    #: only — nothing traced reads it, so numerics stay untouched; the
-    #: Perfetto export (``obs.trace_export``) uses it for the timeline.
+    #: only — nothing traced reads it, so numerics stay untouched.  It is
+    #: not the profiler's clock: timelines come from ``jax.profiler.trace``.
     ts: float = 0.0
 
     def to_json(self) -> Dict[str, Any]:

@@ -6,17 +6,23 @@ across adjoint policy x offload tier x (eager|jit), for the explicit
 tableau family and both implicit theta-methods.  Plus: the adaptive trace
 reconstructs the exact accepted/rejected sequence, spill traffic is
 attributed per store and per segment, the planner's explain report is
-consistent with candidate_costs, and the JSONL sink round-trips.
+consistent with candidate_costs, and the JSONL sink round-trips.  The
+profiler marks: the ``obs:vf`` scope is op metadata only, and every spill
+callback opens one ``obs:spill/*`` span on the profiler's clock.
 """
 from __future__ import annotations
 
+import contextlib
+import gzip
 import json
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.adaptive import odeint_adaptive
+from repro.core import integrators
 from repro.core.adjoint import odeint
 from repro.core.implicit import odeint_implicit
 from repro.mem import offload
@@ -409,3 +415,88 @@ def test_feval_counter_wraps_field():
     assert calls.count == 4  # euler: one f eval per step
     calls.reset()
     assert calls.count == 0
+
+
+# ---------------------------------------------------------------------------
+# profiler marks: the vector-field scope and the spill callbacks' spans
+# ---------------------------------------------------------------------------
+
+def _vf_grad(policy):
+    u0, theta = _problem()
+    kw = {"ncheck": 2} if policy == "revolve" else {}
+
+    def loss(th):
+        return jnp.sum(odeint(_vf, u0, th, dt=0.1, n_steps=6, method="rk4",
+                              adjoint=policy, **kw) ** 2)
+    return jax.jit(jax.grad(loss)), theta
+
+
+@pytest.mark.parametrize("policy", ["pnode", "revolve"])
+def test_vf_scope_marks_both_sweeps(policy):
+    g, theta = _vf_grad(policy)
+    text = g.lower(theta).as_text(debug_info=True)
+    assert re.search(r"obs:vf/(tanh|sin|mul)", text)    # forward
+    assert "transpose(jvp(obs:vf))" in text              # reverse
+    # fwd_sweep_ms/rev_sweep_ms read obs:<name>/fwd|bwd: vf must not match
+    assert not re.search(r"obs:vf/(fwd|bwd)", text)
+    # on the compiled ops, the field sits inside the policy's sweep scopes
+    names = re.findall(r'op_name="([^"]*)"',
+                       g.lower(theta).compile().as_text())
+    assert any(re.search(r"obs:\w+/fwd.*/obs:vf/", n) for n in names)
+    assert any(re.search(r"obs:\w+/bwd.*/transpose\(jvp\(obs:vf\)\)/", n)
+               for n in names)
+
+
+@pytest.mark.parametrize("policy", ["pnode", "revolve"])
+def test_vf_scope_is_metadata_only(policy, monkeypatch):
+    g, theta = _vf_grad(policy)
+    hlo, grad = g.lower(theta).as_text(), g(theta)
+    monkeypatch.setattr(integrators, "scope",
+                        lambda name: contextlib.nullcontext())
+    g0, _ = _vf_grad(policy)
+    lowered = g0.lower(theta)
+    assert "obs:vf" not in lowered.as_text(debug_info=True)
+    assert lowered.as_text() == hlo
+    assert _bitwise(g0(theta), grad)
+
+
+SPILL_SPANS = {f"obs:spill/{n}" for n in
+               ("write", "write_batch", "read", "prefetch", "dispatch",
+                "free")}
+
+
+@pytest.mark.parametrize("policy,one_slot_per_callback,want", [
+    ("pnode", False, 3 + 3 + 2),   # per segment: write, prefetch; dispatch
+    ("pnode", True, 8 + 8 + 2),    # the payload cap lets one slot through
+    ("revolve", False, None),
+])
+def test_spill_callbacks_open_one_span_each(policy, one_slot_per_callback,
+                                            want, tmp_path, monkeypatch):
+    u0, theta = _problem()
+    if one_slot_per_callback:   # the largest leaf of a slot: 4 stages x D
+        monkeypatch.setattr(offload, "_CB_PAYLOAD_CAP",
+                            4 * D * u0.dtype.itemsize)
+    kw = ({"ncheck": 2} if policy == "revolve"
+          else {"offload_segment": 3})
+
+    def loss(th):
+        return jnp.sum(odeint(_vf, u0, th, dt=0.1, n_steps=8, method="rk4",
+                              adjoint=policy, offload="spill", **kw) ** 2)
+
+    g = jax.jit(jax.grad(loss))
+    jax.block_until_ready(g(theta))  # compile + warm
+    before = offload.spill_stats()
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(g(theta))
+    after = offload.spill_stats()
+    calls = sum(after[k] - before[k]
+                for k in ("write_cb", "read_cb", "dispatch_cb", "free_cb"))
+    (path,) = tmp_path.glob("**/*.trace.json.gz")
+    with gzip.open(path, "rt") as fh:
+        events = json.load(fh)["traceEvents"]
+    spans = [e["name"] for e in events
+             if e.get("ph") == "X" and e["name"].startswith("obs:spill/")]
+    assert set(spans) <= SPILL_SPANS
+    assert len(spans) == calls > 0
+    if want is not None:
+        assert calls == want
