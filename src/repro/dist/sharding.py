@@ -24,7 +24,7 @@ Parameter rules (``param_specs``), per leaf role:
                                          mixtral: 8 experts on model=16)
   everything else        generic: largest divisible trailing dims get
                                          'data' then 'model'; small leaves
-                                         (< _REPLICATE_MAX elems) replicate
+                                         (<= _REPLICATE_MAX elems) replicate
 
 Every pin is divisibility-guarded: a dim that the mesh axis product does not
 divide is silently dropped (never an invalid spec), and each mesh axis is
@@ -47,8 +47,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 # mesh axes that carry the global batch, outermost first
 BATCH_AXES = ("pod", "data")
-# leaves smaller than this replicate under the generic rule (norm scales,
-# biases, decay params): sharding them saves nothing and costs collectives
+# leaves of at most this many elements replicate under the generic rule
+# (norm scales, biases, decay params): sharding them saves nothing and costs
+# collectives.  ``replicated_leaf`` is the one test of it
 _REPLICATE_MAX = 65536
 
 
@@ -143,6 +144,33 @@ _COL_NAMES = ("w_gate", "w_up", "w_in", "w_in_gate", "w_in_rnn",
 _ROW_NAMES = ("w_down", "w_out", "w_o")
 
 
+def _role(keys: Sequence[str], nd: int) -> Optional[str]:
+    """The role rule that claims a leaf, from its path and rank; None for
+    the generic rule."""
+    name = keys[-1] if keys else ""
+    if "moe" in keys and name in ("w_gate", "w_up", "w_down") and nd >= 3:
+        return "moe"
+    if name in ("wq", "wk", "wv") and nd >= 3:
+        return "qkv"
+    if name == "wo" and nd >= 3:
+        return "wo"
+    if name == "table" and nd == 2:
+        return "table"
+    if name in _COL_NAMES and nd >= 2:
+        return "col"
+    if name in _ROW_NAMES and nd >= 2:
+        return "row"
+    return None
+
+
+def replicated_leaf(path, shape: Sequence[int]) -> bool:
+    """True for a leaf that ``param_specs`` replicates under every mesh:
+    no role rule claims it and it has at most ``_REPLICATE_MAX`` elements.
+    AdamW packs the moments of exactly these leaves (optim/adamw.py)."""
+    return (_role(_path_keys(path), len(shape)) is None
+            and math.prod(shape) <= _REPLICATE_MAX)
+
+
 def param_specs(cfg, shapes, mesh):
     """Per-leaf PartitionSpec tree for ``lm.init_params(cfg, ...)`` shapes.
 
@@ -154,43 +182,43 @@ def param_specs(cfg, shapes, mesh):
 
     def rule(path, leaf):
         keys = _path_keys(path)
-        name = keys[-1]
         shp = tuple(leaf.shape)
         nd = len(shp)
+        role = _role(keys, nd)
 
         # ---- MoE expert banks: (.., E, D, F) / (.., E, F, D)
-        if "moe" in keys and name in ("w_gate", "w_up", "w_down") and nd >= 3:
+        if role == "moe":
             n_exp = shp[nd - 3]
             if nm > 1 and n_exp % nm == 0:
                 # expert parallelism: one (or more) experts per model shard
                 pins = {nd - 3: "model", nd - 2: "data"}
-            elif name == "w_down":          # TP within expert: F on 'model'
+            elif keys[-1] == "w_down":      # TP within expert: F on 'model'
                 pins = {nd - 2: "model", nd - 1: "data"}
             else:
                 pins = {nd - 1: "model", nd - 2: "data"}
         # ---- attention projections
-        elif name in ("wq", "wk", "wv") and nd >= 3:
+        elif role == "qkv":
             # (.., D, Hx, dh): heads on 'model' when divisible, else head_dim
             pins = {nd - 3: "data"}
             pins[nd - 2 if nm > 1 and shp[nd - 2] % nm == 0 else nd - 1] = \
                 "model"
-        elif name == "wo" and nd >= 3:
+        elif role == "wo":
             # (.., H, dh, D)
             pins = {nd - 1: "data"}
             pins[nd - 3 if nm > 1 and shp[nd - 3] % nm == 0 else nd - 2] = \
                 "model"
         # ---- embeddings / lm head / learned positions: (V, D)
-        elif name == "table" and nd == 2:
+        elif role == "table":
             pins = {0: "model", 1: "data"}
         # ---- dense 2-D projections (FFN, channel-mix, rwkv/rglru mixers)
-        elif name in _COL_NAMES and nd >= 2:
+        elif role == "col":
             pins = {nd - 1: "model", nd - 2: "data"}
-        elif name in _ROW_NAMES and nd >= 2:
+        elif role == "row":
             pins = {nd - 2: "model", nd - 1: "data"}
         # ---- everything else
+        elif replicated_leaf(path, shp):
+            return P(*(None,) * nd)
         else:
-            if math.prod(shp) < _REPLICATE_MAX:
-                return P(*(None,) * nd)
             pins = _generic_pins(shp, keys, mesh)
         return _spec_from_pins(shp, pins, mesh)
 
@@ -198,12 +226,14 @@ def param_specs(cfg, shapes, mesh):
 
 
 def opt_state_specs(pspecs, opt_shape):
-    """Optimizer-state specs: AdamW moments mirror the param tree leaf-for-
-    leaf (the FSDP shards of a param apply to its m and v), scalars
-    replicate."""
+    """Optimizer-state specs with the structure of ``opt_shape`` (the
+    ``AdamWState`` of ``jax.eval_shape(opt.init, params_shape)``): the step
+    and the packed moments replicate, as the params of the packed leaves
+    (``replicated_leaf``) do; every other leaf's m and v take that param's
+    spec, so its FSDP/TP shards apply to its moments."""
     from repro.optim.adamw import AdamWState
-    del opt_shape  # structure is fixed by AdamWState; kept for call-site symmetry
-    return AdamWState(step=P(), m=pspecs, v=pspecs)
+    own = opt_shape.layout.own(pspecs)
+    return AdamWState(P(), P(), P(), own, own, opt_shape.layout)
 
 
 def batch_specs(cfg, cell, mesh) -> Dict[str, P]:
