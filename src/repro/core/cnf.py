@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.core.adjoint import odeint
 from repro.core.integrators import PyTree, VectorField
+from repro.obs.profile import scope
 
 
 def exact_trace_vf(f: VectorField, dim: int) -> VectorField:
@@ -35,8 +36,9 @@ def exact_trace_vf(f: VectorField, dim: int) -> VectorField:
             _, jv = jax.jvp(lambda xx: f(xx, theta, t), (x,), (e,))
             return jv[..., i]
 
-        diag = jnp.stack([jac_diag_i(i) for i in range(dim)], axis=-1)
-        trace = jnp.sum(diag, axis=-1)
+        with scope("cnf/trace"):
+            diag = jnp.stack([jac_diag_i(i) for i in range(dim)], axis=-1)
+            trace = jnp.sum(diag, axis=-1)
         return (fx, -trace)
 
     return aug
@@ -51,11 +53,22 @@ def hutchinson_trace_vf(f: VectorField, probe: jax.Array) -> VectorField:
     def aug(state, theta, t):
         x, _logdet = state
         fx, vjp_fn = jax.vjp(lambda xx: f(xx, theta, t), x)
-        (vjp_probe,) = vjp_fn(probe)
-        trace_est = jnp.sum(vjp_probe * probe, axis=-1)
+        with scope("cnf/trace"):
+            (vjp_probe,) = vjp_fn(probe)
+            trace_est = jnp.sum(vjp_probe * probe, axis=-1)
         return (fx, -trace_est)
 
     return aug
+
+
+def change_of_variables(z: jax.Array, dlogdet: jax.Array) -> jax.Array:
+    """log p(x) from the flow's end point ``z`` and ``dlogdet``, the
+    integral of -tr(df/dx) that the augmented fields above accumulate:
+    log p(x) = log N(z; 0, I) - dlogdet (FFJORD's logpx = logpz -
+    delta_logp).  ``z`` is (..., dim), ``dlogdet`` (...)."""
+    dim = z.shape[-1]
+    base_logp = -0.5 * jnp.sum(z ** 2, axis=-1) - 0.5 * dim * jnp.log(2 * jnp.pi)
+    return base_logp - dlogdet
 
 
 def cnf_log_prob(f: VectorField, x: jax.Array, theta: PyTree, *,
@@ -82,9 +95,7 @@ def cnf_log_prob(f: VectorField, x: jax.Array, theta: PyTree, *,
     logdet0 = jnp.zeros(x.shape[:-1], x.dtype)
     z, dlogdet = odeint(aug, (x, logdet0), theta, dt=dt, n_steps=n_steps,
                         t0=t0, method=method, adjoint=adjoint, ncheck=ncheck)
-    base_logp = -0.5 * jnp.sum(z ** 2, axis=-1) - 0.5 * dim * jnp.log(2 * jnp.pi)
-    # log p(x) = log p_base(z) + integral of -tr(J) accumulated in dlogdet
-    return base_logp + dlogdet
+    return change_of_variables(z, dlogdet)
 
 
 def cnf_sample(f: VectorField, z: jax.Array, theta: PyTree, *, dt: float,

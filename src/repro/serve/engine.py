@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.core.adjoint import odeint
 from repro.core.adaptive import odeint_adaptive
-from repro.core.cnf import exact_trace_vf
+from repro.core.cnf import change_of_variables, exact_trace_vf
 from repro.mem.offload import make_store
 from repro.mem.planner import plan_odeint
 from repro.serve.queue import BucketSpec, RequestQueue, Ticket
@@ -178,8 +178,7 @@ class ODEEngine:
         kw = self._solver_kw(store)
         z, dlogdet = odeint(self._aug, (x, jnp.zeros((), x.dtype)), theta,
                             **kw)
-        return (-0.5 * jnp.sum(z ** 2)
-                - 0.5 * self.dim * jnp.log(2 * jnp.pi) + dlogdet)
+        return change_of_variables(z, dlogdet)
 
     def _fn(self, kind: str, bucket: int) -> Callable:
         """Compiled (kind, bucket) program — at most
@@ -228,8 +227,7 @@ class ODEEngine:
         def logp_one(theta, x):
             (z, dlogdet), _ = odeint_adaptive(
                 self._aug, (x, jnp.zeros((), x.dtype)), theta, **kw)
-            return (-0.5 * jnp.sum(z ** 2)
-                    - 0.5 * self.dim * jnp.log(2 * jnp.pi) + dlogdet)
+            return change_of_variables(z, dlogdet)
 
         def density(theta, x):
             return logp_one(theta, x)

@@ -24,13 +24,35 @@ def test_linear_flow_logdet_exact():
     T, n = 1.0, 50
     lp = cnf_log_prob(f, x, A, dt=T / n, n_steps=n, method="rk4",
                       adjoint="naive")
-    # z = expm(A) x; log p(x) = log N(z; 0, I) + T tr(A)... with sign:
-    # d logdet/dt = -tr(A) accumulated, so lp = logN(z) - T tr(A) + T tr(A)?
+    # z = expm(T A) x, so log p(x) = log N(z) + log|det dz/dx|
+    # = log N(z) + T tr(A): the field accumulates -T tr(A) and
+    # cnf_log_prob subtracts it
     z = x @ jax.scipy.linalg.expm(A).T
     base = -0.5 * jnp.sum(z ** 2, -1) - 0.5 * d * jnp.log(2 * jnp.pi)
-    expected = base - T * jnp.trace(A)
+    expected = base + T * jnp.trace(A)
     np.testing.assert_allclose(np.asarray(lp), np.asarray(expected),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("trace", ["exact", "hutchinson"])
+def test_linear_flow_density_integrates_to_one(trace):
+    """On the 1-D flow f = a u, z = x e^a and log p(x) = log N(z) + a:
+    the program's density integrates to 1 (a density with the sign of
+    the log-determinant flipped integrates to e^(-2a))."""
+    a = 0.7
+
+    def f(u, th, t):
+        return th * u
+
+    xs = jnp.linspace(-12.0, 12.0, 20001)[:, None]
+    probe = jnp.ones_like(xs) if trace == "hutchinson" else None
+    lp = cnf_log_prob(f, xs, jnp.asarray(a), dt=1.0 / 32, n_steps=32,
+                      method="rk4", adjoint="pnode", trace=trace,
+                      probe=probe)
+    mass = float(jnp.sum(jnp.exp(lp)) * (xs[1, 0] - xs[0, 0]))
+    assert abs(mass - 1.0) < 1e-3, mass
+    assert np.isclose(float(lp[10000]), -0.5 * np.log(2 * np.pi) + a,
+                      atol=1e-6)
 
 
 @pytest.mark.parametrize("adjoint", ["pnode", "pnode2", "aca"])

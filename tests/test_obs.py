@@ -7,8 +7,9 @@ tableau family and both implicit theta-methods.  Plus: the adaptive trace
 reconstructs the exact accepted/rejected sequence, spill traffic is
 attributed per store and per segment, the planner's explain report is
 consistent with candidate_costs, and the JSONL sink round-trips.  The
-profiler marks: the ``obs:vf`` scope is op metadata only, and every spill
-callback opens one ``obs:spill/*`` span on the profiler's clock.
+profiler marks: the ``obs:vf`` and ``obs:cnf/trace`` scopes are op
+metadata only, and every spill callback opens one ``obs:spill/*`` span on
+the profiler's clock.
 """
 from __future__ import annotations
 
@@ -22,11 +23,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.adaptive import odeint_adaptive
-from repro.core import integrators
+from repro.core import cnf, integrators
 from repro.core.adjoint import odeint
 from repro.core.implicit import odeint_implicit
 from repro.mem import offload
 from repro.mem.planner import candidate_costs, plan_odeint
+from repro.models.ode_nets import cnf_vf, cnf_vf_init
 from repro.obs import (FevalCounter, FlightRecorder, Gate, JitCounter,
                        MetricsRegistry, MetricsSink, StructuredLogger,
                        check_against_baseline, read_jsonl)
@@ -458,6 +460,43 @@ def test_vf_scope_is_metadata_only(policy, monkeypatch):
     assert "obs:vf" not in lowered.as_text(debug_info=True)
     assert lowered.as_text() == hlo
     assert _bitwise(g0(theta), grad)
+
+
+def _cnf_grad(trace):
+    theta = cnf_vf_init(jax.random.PRNGKey(0), D, hidden=(8, 8))
+    x = jax.random.normal(jax.random.PRNGKey(1), (5, D))
+    probe = jax.random.rademacher(jax.random.PRNGKey(2), (5, D), x.dtype)
+
+    def loss(th):
+        return -jnp.mean(cnf.cnf_log_prob(
+            cnf_vf, x, th, dt=0.25, n_steps=4, method="rk4",
+            adjoint="pnode", trace=trace, probe=probe))
+    return jax.jit(jax.value_and_grad(loss)), theta
+
+
+@pytest.mark.parametrize("trace", ["hutchinson", "exact"])
+def test_cnf_trace_scope_inside_the_field_in_both_sweeps(trace):
+    g, theta = _cnf_grad(trace)
+    assert "obs:cnf/trace" in g.lower(theta).as_text(debug_info=True)
+    names = re.findall(r'op_name="([^"]*)"',
+                       g.lower(theta).compile().as_text())
+    # trace_est_ms reads obs:cnf/trace, vf_ms the obs:vf around it
+    assert any(re.search(r"obs:\w+/fwd\).*/obs:vf/obs:cnf/trace/", n)
+               for n in names)
+    assert any(re.search(r"obs:\w+/bwd\).*/transpose\(jvp\(obs:vf\)\)/"
+                         r"obs:cnf/trace/", n) for n in names)
+
+
+@pytest.mark.parametrize("trace", ["hutchinson", "exact"])
+def test_cnf_trace_scope_is_metadata_only(trace, monkeypatch):
+    g, theta = _cnf_grad(trace)
+    hlo, out = g.lower(theta).as_text(), g(theta)
+    monkeypatch.setattr(cnf, "scope", lambda name: contextlib.nullcontext())
+    g0, _ = _cnf_grad(trace)
+    lowered = g0.lower(theta)
+    assert "obs:cnf/trace" not in lowered.as_text(debug_info=True)
+    assert lowered.as_text() == hlo
+    assert _bitwise(g0(theta), out)
 
 
 SPILL_SPANS = {f"obs:spill/{n}" for n in

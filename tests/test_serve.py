@@ -17,7 +17,7 @@ import pytest
 
 from repro.core.adaptive import odeint_adaptive
 from repro.core.adjoint import odeint
-from repro.core.cnf import exact_trace_vf
+from repro.core.cnf import change_of_variables, exact_trace_vf
 from repro.mem.offload import make_store
 from repro.mem.planner import plan_odeint
 from repro.models.ode_nets import cnf_vf, cnf_vf_init
@@ -50,7 +50,7 @@ def xs():
 
 
 def _logp_ref(**kw):
-    """Unbatched reference density (same formula the engine uses).  Takes
+    """Unbatched reference density (the engine's change of variables).  Takes
     theta as a traced ARGUMENT like the engine's compiled programs do —
     closing over it would let XLA constant-fold differently and shift the
     last ulp."""
@@ -60,8 +60,7 @@ def _logp_ref(**kw):
         z, dl = odeint(aug, (x_, jnp.zeros((), x_.dtype)), th,
                        dt=DT, n_steps=N_STEPS, method="rk4",
                        adjoint="pnode", **kw)
-        return (-0.5 * jnp.sum(z ** 2)
-                - 0.5 * DIM * jnp.log(2 * jnp.pi) + dl)
+        return change_of_variables(z, dl)
 
     return logp
 
@@ -213,8 +212,7 @@ def test_engine_bitwise_adaptive(theta, xs):
             aug, (x_, jnp.zeros((), x_.dtype)), th, t0=0.0, t1=t1,
             rtol=1e-6, atol=1e-6, max_steps=64, offload="spill",
             offload_segment=SEG)
-        return (-0.5 * jnp.sum(z ** 2)
-                - 0.5 * DIM * jnp.log(2 * jnp.pi) + dl)
+        return change_of_variables(z, dl)
 
     td = [eng.submit("density", x) for x in xs[:2]]
     ts = [eng.submit("score", x) for x in xs[:2]]
@@ -319,8 +317,7 @@ def test_departure_frees_slots(theta, xs):
                                dt=DT, n_steps=N_STEPS, method="rk4",
                                adjoint="pnode", offload="spill",
                                offload_segment=SEG, offload_store=store)
-                return (-0.5 * jnp.sum(z ** 2)
-                        - 0.5 * DIM * jnp.log(2 * jnp.pi) + dl)
+                return change_of_variables(z, dl)
             return jax.grad(logp)(x_)
         return jax.vmap(one)(xb)
 
