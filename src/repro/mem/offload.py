@@ -105,6 +105,45 @@ the BENCH gates price the real host round-trips.  A single slot bigger
 than the cap cannot be split further (warned; the slot-addressed
 ``put``/``write_at`` paths have the same exposure).
 
+Payload ownership: on jax 0.9 every in-jit ``pure_callback`` reaches
+the store through ``pure_callback_impl``, which first ``device_put``s the
+operands to the CPU device: an asynchronous copy of the numpy buffer
+jaxlib filled for the callback, or, where that buffer is 64-byte aligned,
+an alias that holds it (that buffer owns its bytes on XLA:CPU and on a TPU
+v5e host alike).  So a write callback receives CPU ``jax.Array``s that
+JAX owns and nothing else writes to, and the store keeps those arrays as
+they are: holding one keeps its buffer alive, and each slot is a view of
+the arrays its write kept, at the slot's index.  The views are made when
+a slot is first read, so the write callback
+returns without waiting for jax's own (asynchronous) copy of the operands
+to land, and copies no payload byte.  The read callbacks hand the stored
+bytes back as they are: one slot as a view, slots that sit at even steps
+in one kept array as one strided view of it (what a read of a chunk a
+write stored gives), anything else through one ``np.stack``.  XLA copies
+a callback's results into its own buffers, so nothing aliases the store.
+The paths that still copy, each because a view would be wrong or a stack
+is the only way to assemble the rows:
+
+  * an operand that is not a ``jax.Array`` (a numpy array, e.g. from a
+    caller that invokes a callback body directly, or a jax that passes
+    raw buffers): its producer may reuse or write the bytes;
+  * a lane-keyed chunk with padding lanes (``None`` keys): a view of a
+    real lane would pin the padding lanes' bytes, which no slot accounts
+    for, so the real lanes' slots are copied out;
+  * a ``corrupt`` write fault: ``FaultPlan.corrupt_arrays`` returns fresh
+    corrupted arrays (the clean operand is never written);
+  * a read whose rows do not sit at even steps in one buffer (rows from
+    disk files, or slots written by different callbacks): one ``np.stack``;
+  * a read with a missing slot, a failed checked read, or a lane-keyed
+    read with padding lanes: the zero-filled stack the caller masks.
+
+``host_copy_bytes`` counts the payload bytes these paths copy or
+zero-fill (the integrity checksum's staging and disk I/O are not
+counted), so it reads 0 on an all-RAM sweep of plain slots.  Because a
+slot views all that its write kept, the RAM gauge counts each write's
+arrays once, for as long as any slot views them: freeing some of a
+chunk's slots releases nothing until the last one goes.
+
 Counters: every store keeps its own host-side callback counters
 (``store.stats``, keyed by an auto-assigned ``store_id``) and mirrors each
 increment into a process-wide aggregate — ``spill_stats()`` returns the
@@ -119,13 +158,15 @@ traces.  ``read_cb``/``write_cb`` count data-carrying round-trips only;
 ``dispatch_cb`` counts the token-only async-issue callbacks separately so
 the BENCH_3 callbacks-per-reverse-pass gates keep their historical
 meaning.  ``disk_write_bytes``/``disk_read_bytes`` break the byte traffic
-down by medium, and ``ram_bytes_peak`` is a high-water gauge of the RAM
+down by medium, ``host_copy_bytes`` counts the store's own payload copies
+(see above), and ``ram_bytes_peak`` is a high-water gauge of the RAM
 dict (max-merged into the aggregate; zeroed by ``reset_spill_stats``) —
 the number the BENCH_6 RAM-budget gate checks.  Attaching a
 ``repro.obs.FlightRecorder`` via ``bind_obs`` makes every callback
 additionally record a ``spill.write``/``spill.read``/``spill.free``/
 ``spill.dispatch`` trace event carrying the store id, slot base, slot
-count, payload bytes, and the medium (``tier="ram"|"disk"|"mixed"``) —
+count, payload bytes, the medium (``medium="ram"|"disk"``) and, on
+writes and reads, the bytes the store copied (``host_copy_bytes``) —
 recorded purely host-side inside the callbacks that already run, so the
 traced program is unchanged and grads stay bitwise identical with obs on.
 
@@ -299,12 +340,13 @@ def _chunk_slots(seg: int, per_slot_bytes: int) -> int:
 #: files; ``ram_bytes_peak`` is a high-water gauge (max-merged, not
 #: summed) of the RAM dict; ``retry_cb`` counts read attempts repeated
 #: after an injected flake and ``integrity_fail`` slots that failed their
-#: checksum/presence check.
+#: checksum/presence check; ``host_copy_bytes`` counts the payload bytes
+#: the store itself copies or zero-fills on the host (module docstring).
 _STAT_KEYS = ("write_cb", "read_cb", "free_cb",
               "write_slots", "read_slots", "write_bytes", "read_bytes",
               "dispatch_cb", "prefetch_hit_cb",
               "disk_write_bytes", "disk_read_bytes", "ram_bytes_peak",
-              "retry_cb", "integrity_fail")
+              "retry_cb", "integrity_fail", "host_copy_bytes")
 
 #: guards ALL counter mutation and the reset: callbacks execute on XLA's
 #: thread pool, concurrently with each other (chunked/vmapped programs)
@@ -385,6 +427,77 @@ def _crc_leaves(arrs) -> int:
     for a in arrs:
         c = zlib.crc32(np.ascontiguousarray(a).tobytes(), c)
     return c
+
+
+class _Kept:
+    """The arrays one write callback keeps; slots view them at an index
+    (``_leaves``).  The numpy views are made on first use, so a write that
+    keeps jax's own operands returns without waiting for jax's copy of them
+    to the CPU device to land."""
+
+    __slots__ = ("arrays", "nbytes", "_views")
+
+    def __init__(self, arrays):
+        self.arrays = list(arrays)
+        self.nbytes = sum(int(a.nbytes) for a in self.arrays)
+        self._views = None
+
+    def views(self) -> List[np.ndarray]:
+        if self._views is None:
+            self._views = [np.asarray(a) for a in self.arrays]
+        return self._views
+
+
+def _keep(operands) -> Tuple[_Kept, int]:
+    """Keep a write callback's operands, and say how many bytes that
+    copied: a ``jax.Array`` as it is (JAX owns its buffer, and holding the
+    array keeps it alive), any other operand (a numpy array, which its
+    producer may reuse or write to) as a copy."""
+    arrays = [a if isinstance(a, jax.Array) else np.array(a)
+              for a in operands]
+    return _Kept(arrays), sum(int(b.nbytes) for a, b in zip(operands, arrays)
+                              if a is not b)
+
+
+def _leaves(entry) -> List[np.ndarray]:
+    """A stored slot's leaves: views, at its index, of the arrays kept."""
+    kept, ix = entry
+    return [v[ix] for v in kept.views()]
+
+
+def _root(a):
+    """The object that owns the bytes ``a`` views (``a`` itself when it
+    owns them)."""
+    while isinstance(a, np.ndarray) and a.base is not None:
+        a = a.base
+    return a
+
+
+def _addr(a: np.ndarray) -> int:
+    return a.__array_interface__["data"][0]
+
+
+def _strided_view(rows, grid: Tuple[int, ...], axis: int):
+    """One read-only view equal to ``np.stack(rows, axis)`` (reshaped so
+    the grid's axes replace the stacked one), when the rows — given in C
+    order over ``grid`` — sit at even steps in one buffer, as the slots a
+    write stored from one chunk do; else None.  Every element of the view
+    is an element of some row, so it reads only live memory."""
+    r0 = rows[0]
+    root, p0 = _root(r0), _addr(r0)
+    units = [int(np.prod(grid[j + 1:], dtype=np.int64))
+             for j in range(len(grid))]
+    steps = [(_addr(rows[u]) - p0) if n > 1 else 0
+             for n, u in zip(grid, units)]
+    for idx, r in zip(np.ndindex(*grid), rows):
+        if (r.shape != r0.shape or r.strides != r0.strides
+                or r.dtype != r0.dtype or _root(r) is not root
+                or _addr(r) != p0 + sum(i * d for i, d in zip(idx, steps))):
+            return None
+    return np.lib.stride_tricks.as_strided(
+        r0, r0.shape[:axis] + tuple(grid) + r0.shape[axis:],
+        r0.strides[:axis] + tuple(steps) + r0.strides[axis:],
+        writeable=False)
 
 
 def _slot_salt(slot) -> int:
@@ -615,7 +728,9 @@ class SpillStore(CheckpointStore):
 
     def __init__(self):
         super().__init__()
-        self._host: Dict[Any, List[np.ndarray]] = {}
+        #: slot -> (_Kept, index): the slot's leaves are views of the
+        #: kept arrays at that index (``_leaves``)
+        self._host: Dict[Any, Tuple[_Kept, tuple]] = {}
         self._meta: Dict[Any, Tuple[Any, Tuple[jax.ShapeDtypeStruct, ...]]] = {}
         self._tok = None
         self.effective_tier = self.tier
@@ -648,6 +763,10 @@ class SpillStore(CheckpointStore):
         #: (None = unlimited — the historical all-RAM store)
         self.snaps_in_ram: Optional[int] = None
         self._ram_bytes = 0
+        #: id(_Kept) -> [kept, slots viewing it]: a slot pins all the
+        #: arrays its write kept, so RAM bytes count each _Kept once
+        #: while any slot views it
+        self._pins: Dict[int, list] = {}
         self._disk_dir: Optional[str] = None
         self._disk_dir_owned = False
         self._disk: Dict[int, str] = {}            # slot -> segment file
@@ -689,13 +808,24 @@ class SpillStore(CheckpointStore):
                              self._disk_dir, True)
         return self._disk_dir
 
-    def _host_insert(self, slot, leaves) -> None:
+    def _pin(self, kept: _Kept, n: int) -> None:
+        # under _io_lock; n = +1 when a slot takes a view of ``kept``, -1
+        # when it lets it go
+        ent = self._pins.setdefault(id(kept), [kept, 0])
+        if ent[1] == 0:
+            self._ram_bytes += kept.nbytes
+        ent[1] += n
+        if ent[1] == 0:
+            del self._pins[id(kept)]
+            self._ram_bytes -= kept.nbytes
+
+    def _host_insert(self, slot, entry) -> None:
         # under _io_lock
         old = self._host.get(slot)
+        self._host[slot] = entry
+        self._pin(entry[0], 1)
         if old is not None:
-            self._ram_bytes -= sum(a.nbytes for a in old)
-        self._host[slot] = leaves
-        self._ram_bytes += sum(a.nbytes for a in leaves)
+            self._pin(old[0], -1)
         with _STATS_LOCK:
             if self._ram_bytes > self.stats["ram_bytes_peak"]:
                 self.stats["ram_bytes_peak"] = self._ram_bytes
@@ -708,7 +838,7 @@ class SpillStore(CheckpointStore):
         with self._io_lock:
             old = self._host.pop(slot, None)
             if old is not None:
-                self._ram_bytes -= sum(a.nbytes for a in old)
+                self._pin(old[0], -1)
             path = self._disk.pop(slot, None)
             if path is not None:
                 live = self._file_slots.get(path)
@@ -731,14 +861,14 @@ class SpillStore(CheckpointStore):
                                           if s not in self._host)
         return projected <= self.snaps_in_ram
 
-    def _disk_write_rows(self, rows: Dict[int, List[np.ndarray]]) -> int:
+    def _disk_write_rows(self, rows: Dict[Any, tuple]) -> int:
         # under _io_lock; one savez extent per write batch, no pickle
         path = os.path.join(
             self._disk_root(),
             f"{_DISK_PREFIX}{self.store_id}_{next(self._file_seq)}.npz")
         payload = {f"s{slot}_l{k}": a
-                   for slot, leaves in rows.items()
-                   for k, a in enumerate(leaves)}
+                   for slot, entry in rows.items()
+                   for k, a in enumerate(_leaves(entry))}
         np.savez(path, **payload)
         self._created.append(path)
         self._file_slots[path] = set(rows)
@@ -747,20 +877,19 @@ class SpillStore(CheckpointStore):
             self._drop_slot(slot)
             self._disk[slot] = path
             self._file_slots[path].add(slot)
-        return sum(a.nbytes for leaves in rows.values() for a in leaves)
+        return sum(a.nbytes for a in payload.values())
 
-    def _store_rows(self, rows: Dict[int, List[np.ndarray]]
-                    ) -> Tuple[str, int]:
+    def _store_rows(self, rows: Dict[Any, tuple]) -> Tuple[str, int]:
         """Route a batch of slots to RAM or disk per ``snaps_in_ram``.
         Returns ``(medium, disk_bytes)`` for counters/obs."""
         if not rows:
             return "ram", 0
         with self._io_lock:
             if self._ram_has_room(rows):
-                for slot, leaves in rows.items():
+                for slot, entry in rows.items():
                     if slot in self._disk:
                         self._drop_slot(slot)
-                    self._host_insert(slot, leaves)
+                    self._host_insert(slot, entry)
                 return "ram", 0
             dbytes = self._disk_write_rows(rows)
         with _STATS_LOCK:
@@ -789,9 +918,9 @@ class SpillStore(CheckpointStore):
         """One slot's leaves from whichever medium holds it (None if
         missing).  Second element reports disk bytes moved."""
         with self._io_lock:
-            leaves = self._host.get(slot)
-            if leaves is not None:
-                return leaves, 0
+            entry = self._host.get(slot)
+            if entry is not None:
+                return _leaves(entry), 0
             leaves = self._disk_read_slot(slot)
             if leaves is None:
                 return None, 0
@@ -889,21 +1018,25 @@ class SpillStore(CheckpointStore):
             self.stats[key] += n
             _AGG[key] += n
 
-    def _apply_write_fault(self, spec, slot: int, arrs):
-        """Apply a ticked ``spill.write`` fault to one slot's payload:
-        ``drop`` loses it in transit (returns None, nothing stored),
-        ``corrupt`` returns deterministically flipped bytes.  Checksums
-        are recorded over the clean payload BEFORE this runs — the
-        corruption-at-rest model the read-side verify detects (on the
-        disk tier the flipped bytes land in the segment file)."""
+    def _seal(self, spec, slot, entry):
+        """Checksum one slot's clean payload (with ``integrity``), then
+        apply a ticked ``spill.write`` fault to it: ``drop`` loses it in
+        transit (None, nothing stored), ``corrupt`` returns fresh,
+        deterministically flipped copies — the corruption-at-rest model
+        the read-side verify detects (on the disk tier the flipped bytes
+        land in the segment file).  Returns ``(entry, bytes copied)``."""
+        if self.integrity:
+            self._sums[slot] = _crc_leaves(_leaves(entry))
         if spec is None:
-            return arrs
+            return entry, 0
         if spec.kind == "drop":
             self._drop_slot(slot)
-            return None
+            return None, 0
         if spec.kind == "corrupt":
-            return self.fault_plan.corrupt_arrays(arrs, salt=_slot_salt(slot))
-        return arrs
+            bad = _Kept(self.fault_plan.corrupt_arrays(
+                _leaves(entry), salt=_slot_salt(slot)))
+            return (bad, (...,)), bad.nbytes
+        return entry, 0
 
     def _read_attempt_ok(self, base: int) -> bool:
         """One logical read, retried with exponential backoff while the
@@ -944,7 +1077,7 @@ class SpillStore(CheckpointStore):
 
     # -- counting + obs (host-side, called from the callbacks) --------------
     def _tally(self, direction: str, *, slots: int, nbytes: int, base,
-               medium: str = "ram", disk_bytes: int = 0):
+               medium: str = "ram", disk_bytes: int = 0, copied: int = 0):
         """Bump this store's counters and the aggregate in lockstep (under
         the module lock — see module docstring), then record an obs event
         if a recorder is bound.  Runs on XLA's callback thread."""
@@ -955,32 +1088,37 @@ class SpillStore(CheckpointStore):
             else:
                 keys = [(f"{direction}_cb", 1),
                         (f"{direction}_slots", slots),
-                        (f"{direction}_bytes", nbytes)]
+                        (f"{direction}_bytes", nbytes),
+                        ("host_copy_bytes", copied)]
                 if direction == "read" and disk_bytes:
                     keys.append(("disk_read_bytes", disk_bytes))
                 for key, n in keys:
                     self.stats[key] += n
                     _AGG[key] += n
         if self._obs is not None:
+            extra = {} if direction == "free" else {"host_copy_bytes": copied}
             self._obs.record(f"spill.{direction}", _runtime=True,
                              store=self.store_id, base=base,
-                             slots=slots, bytes=nbytes, medium=medium)
+                             slots=slots, bytes=nbytes, medium=medium,
+                             **extra)
 
     # -- host-side callbacks (never traced) ---------------------------------
+    def _write_slot(self, spec, slot: int, leaves) -> None:
+        """Store one slot from the callback's operands (kept as they are
+        where JAX owns them — module docstring) and count the write."""
+        kept, copied = _keep(leaves)
+        entry, c = self._seal(spec, slot, (kept, (...,)))
+        medium = "ram"
+        if entry is not None:
+            medium, _ = self._store_rows({slot: entry})
+        self._tally("write", slots=1, nbytes=kept.nbytes, base=slot,
+                    medium=medium, copied=copied + c)
+
     def _cb_write(self, token, slot, *leaves):
         with host_annotation("spill/write"):
             spec = (self.fault_plan.tick("spill.write")
                     if self.fault_plan is not None else None)
-            arrs = [np.asarray(x).copy() for x in leaves]
-            if self.integrity:
-                self._sums[int(slot)] = _crc_leaves(arrs)
-            arrs = self._apply_write_fault(spec, int(slot), arrs)
-            medium = "ram"
-            if arrs is not None:
-                medium, _ = self._store_rows({int(slot): arrs})
-            self._tally("write", slots=1,
-                        nbytes=sum(np.asarray(x).nbytes for x in leaves),
-                        base=int(slot), medium=medium)
+            self._write_slot(spec, int(slot), leaves)
         return np.float32(0)
 
     def _cb_write_if(self, token, slot, keep, *leaves):
@@ -988,16 +1126,7 @@ class SpillStore(CheckpointStore):
             spec = (self.fault_plan.tick("spill.write")
                     if self.fault_plan is not None else None)
             if bool(keep):
-                arrs = [np.asarray(x).copy() for x in leaves]
-                if self.integrity:
-                    self._sums[int(slot)] = _crc_leaves(arrs)
-                arrs = self._apply_write_fault(spec, int(slot), arrs)
-                medium = "ram"
-                if arrs is not None:
-                    medium, _ = self._store_rows({int(slot): arrs})
-                self._tally("write", slots=1,
-                            nbytes=sum(np.asarray(x).nbytes for x in leaves),
-                            base=int(slot), medium=medium)
+                self._write_slot(spec, int(slot), leaves)
             else:  # masked out: the round-trip still happened
                 self._tally("write", slots=0, nbytes=0, base=int(slot))
         return np.float32(0)
@@ -1060,38 +1189,33 @@ class SpillStore(CheckpointStore):
             bnd = np.ndim(token)
             seg = int(np.shape(stacked[0])[bnd])
             base = int(np.ravel(base)[0])  # broadcast copies are identical
-            arrs = [np.asarray(x) for x in stacked]
+            kept, copied = _keep(stacked)
             keys = self.lane_keys
-            rows: Dict[Any, List[np.ndarray]] = {}
             if keys is not None:
-                self._check_lanes(bnd, np.shape(arrs[0]), keys)
-                for b, rk in enumerate(keys):
-                    if rk is None:  # padding lane: nothing stored
-                        continue
-                    for i in range(seg):
-                        key = (rk, base + i)
-                        slot_arrs = [np.asarray(a[b, i]).copy()
-                                     for a in arrs]
-                        if self.integrity:
-                            self._sums[key] = _crc_leaves(slot_arrs)
-                        slot_arrs = self._apply_write_fault(spec, key,
-                                                            slot_arrs)
-                        if slot_arrs is not None:
-                            rows[key] = slot_arrs
+                self._check_lanes(bnd, np.shape(stacked[0]), keys)
+                slots = [((rk, base + i), (b, i, ...))
+                         for b, rk in enumerate(keys) if rk is not None
+                         for i in range(seg)]
             else:
                 sl = (slice(None),) * bnd
-                for i in range(seg):
-                    slot_arrs = [a[sl + (i,)].copy() for a in arrs]
-                    if self.integrity:
-                        self._sums[base + i] = _crc_leaves(slot_arrs)
-                    slot_arrs = self._apply_write_fault(spec, base + i,
-                                                        slot_arrs)
-                    if slot_arrs is not None:
-                        rows[base + i] = slot_arrs
+                slots = [(base + i, sl + (i, ...)) for i in range(seg)]
+            # a view of a real lane would pin the padding lanes' bytes,
+            # which no slot accounts for: copy each slot out instead
+            padded = keys is not None and any(rk is None for rk in keys)
+            rows: Dict[Any, tuple] = {}
+            for key, ix in slots:
+                entry = (kept, ix)
+                if padded:
+                    own, c = _keep(_leaves(entry))
+                    entry = (own, (...,))
+                    copied += c
+                entry, c = self._seal(spec, key, entry)
+                copied += c
+                if entry is not None:
+                    rows[key] = entry
             medium, _ = self._store_rows(rows)
-            self._tally("write", slots=seg,
-                        nbytes=sum(a.nbytes for a in arrs), base=base,
-                        medium=medium)
+            self._tally("write", slots=seg, nbytes=kept.nbytes, base=base,
+                        medium=medium, copied=copied)
         return np.zeros(np.shape(token), np.float32)
 
     def _cb_dispatch(self, seg, m):
@@ -1159,21 +1283,32 @@ class SpillStore(CheckpointStore):
                         else self._gather_rows(base, seg))
                 if hit:
                     self._tally_counter("prefetch_hit_cb")
-                out = []
+                # the rows in C order over the grid that replaces the
+                # slot axis: (lane, slot) when keyed, (slot,) otherwise
+                if keys is not None:
+                    grid, axis = (len(keys), seg), 0
+                    flat = [r for lane in rows for r in lane]
+                else:
+                    grid, axis = (seg,), bnd
+                    flat = rows
+                out, copied = [], 0
                 for k, s in enumerate(sds):
-                    stack = np.zeros(bshape + (seg,) + tuple(s.shape),
-                                     s.dtype)
-                    if ok:
-                        if keys is not None:
-                            # per-lane keyed rows (padding lanes -> zeros)
-                            for b in range(len(keys)):
-                                for i in range(seg):
-                                    if rows[b][i] is not None:
-                                        stack[b, i] = rows[b][i][k]
-                        else:
-                            for i in range(seg):
-                                if rows[i] is not None:  # missing -> zeros
-                                    stack[sl + (i,)] = rows[i][k]
+                    shape = bshape + (seg,) + tuple(s.shape)
+                    leaf = [None if r is None else r[k] for r in flat]
+                    if ok and all(r is not None for r in leaf):
+                        # every row in RAM or read: hand the stored bytes
+                        # back, as one view when they sit in one chunk
+                        stack = _strided_view(leaf, grid, axis)
+                        if stack is None:
+                            stack = np.stack(leaf, axis).reshape(shape)
+                            copied += stack.nbytes
+                    else:  # missing slots and padding lanes read zeros
+                        stack = np.zeros(shape, s.dtype)
+                        copied += stack.nbytes
+                        if ok:
+                            for idx, r in zip(np.ndindex(*grid), leaf):
+                                if r is not None:
+                                    stack[sl[:axis] + idx] = r
                     out.append(stack)
                 if checked and ok:
                     if keys is not None:
@@ -1205,7 +1340,7 @@ class SpillStore(CheckpointStore):
                             nbytes=sum(a.nbytes for a in out), base=base,
                             medium=("disk" if dbytes else "ram") if ok
                             else "ram",
-                            disk_bytes=dbytes)
+                            disk_bytes=dbytes, copied=copied)
                 res = (np.zeros(bshape, np.float32),)
                 if checked:
                     res = res + (np.full(bshape, ok, bool),)

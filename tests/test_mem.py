@@ -7,6 +7,8 @@ operand values) is unchanged.  Planner monotonicity and the auto-policy
 budget check are deterministic parametrized cases (no hypothesis — the
 offline stub has no shrinking to offer here anyway).
 """
+import gc
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +16,7 @@ import pytest
 
 from repro.core.adaptive import odeint_adaptive
 from repro.core.adjoint import odeint
+from repro.ft import FaultPlan, FaultSpec
 from repro.mem import (DeviceStore, HostStore, SpillStore, candidate_costs,
                        host_memory_kind, measure_reverse_cost,
                        plan_depth_remat, plan_odeint, policy_cost,
@@ -143,6 +146,140 @@ def test_device_store_pack_order_matches_slots():
     st2 = DeviceStore()
     st2.unpack(("x0", "x7"), [0, 7])
     assert st2.get(7) == "x7"
+
+
+# ---------------------------------------------------------------------------
+# spill payload ownership: the store keeps the arrays jax hands its write
+# callbacks and returns them as they are, copying only where it must
+# ---------------------------------------------------------------------------
+
+def _churn():
+    # unrelated jitted work of the checkpoints' sizes, then a collection:
+    # a kept view whose buffer had been released would now read garbage
+    junk = [jax.jit(lambda z, k=k: z * 3.0 + k)(jnp.ones((4, 3)))
+            for k in range(64)]
+    jax.block_until_ready(junk)
+    del junk
+    gc.collect()
+
+
+def test_spill_views_outlive_the_write_call():
+    st = offload_mod.make_store("spill")
+    x = jax.random.normal(jax.random.PRNGKey(3), (4, 3))
+    y = jax.random.normal(jax.random.PRNGKey(4), (3,))
+    # doubling is exact, so the reference matches the written bytes
+    tok = jax.jit(lambda t, x: st.write_batch(t, 0, 2.0 * x))(
+        st.init_token(), x)
+    tok = jax.jit(lambda t, y: st.write_at(t, jnp.int32(4), 2.0 * y))(tok, y)
+    jax.block_until_ready(tok)
+    # the store kept jax's own arrays, not copies of them
+    assert all(isinstance(a, jax.Array)
+               for kept, _ in st._host.values() for a in kept.arrays)
+    _churn()
+    # each read is of what one write stored, so it returns views too
+    tok, seg = jax.jit(lambda t: st.prefetch(t, 0, 4))(tok)
+    tok, row = jax.jit(lambda t: st.prefetch(t, 4, 1))(tok)
+    np.testing.assert_array_equal(np.asarray(seg), np.asarray(2.0 * x))
+    np.testing.assert_array_equal(np.asarray(row[0]), np.asarray(2.0 * y))
+    assert st.stats["host_copy_bytes"] == 0
+
+
+def test_pnode_spill_bitwise_over_consecutive_steps():
+    f = _vf()
+
+    def gfn(offload):
+        def L(u0_, th_):
+            return jnp.sum(odeint(f, u0_, th_, dt=DT, n_steps=N_STEPS,
+                                  adjoint="pnode", offload=offload) ** 2)
+        return jax.jit(jax.grad(L, argnums=(0, 1)))
+
+    spill, ref = gfn("spill"), gfn(None)
+    for seed in (0, 1):  # the second step overwrites the first's slots
+        u0, th = _problem(seed)
+        g = spill(u0, th)
+        _churn()
+        _assert_bitwise(g, ref(u0, th))
+
+
+def _lanes(keys):
+    # one vmapped write and read of 3 slots per lane under lane keys
+    st = offload_mod.make_store("spill")
+    st.lane_keys = keys
+    xs = jnp.arange(18.0).reshape(2, 3, 3)
+    tok = jax.jit(jax.vmap(lambda t, x: st.write_batch(t, 0, x)))(
+        jnp.zeros(2, jnp.float32), xs)
+    tok, seg = jax.jit(jax.vmap(lambda t: st.prefetch(t, 0, 3)))(tok)
+    want = np.asarray(xs) * np.array([k is not None for k in keys])[:, None,
+                                                                     None]
+    np.testing.assert_array_equal(np.asarray(seg), want)
+    return st.stats["host_copy_bytes"]
+
+
+def _corrupt_write():
+    st = offload_mod.make_store(
+        "spill", integrity=True,
+        fault_plan=FaultPlan([FaultSpec("spill.write", 0, "corrupt")]))
+    tok = jax.jit(lambda t, x: st.write_batch(t, 0, x))(
+        st.init_token(), jnp.arange(12.0).reshape(4, 3))
+    jax.block_until_ready(tok)
+    copied = st.stats["host_copy_bytes"]
+    _, ok, _ = jax.jit(lambda t: st.prefetch_checked(t, 0, 4))(tok)
+    assert not bool(ok)  # the read-side checksum still sees the damage
+    return copied
+
+
+def _missing_row():
+    st = offload_mod.make_store("spill")
+    tok = jax.jit(lambda t, x: st.write_batch(t, 0, x))(
+        st.init_token(), jnp.arange(1.0, 7.0).reshape(2, 3))
+    _, seg = jax.jit(lambda t: st.prefetch(t, 0, 4))(tok)
+    assert np.all(np.asarray(seg)[2:] == 0.0)  # never written: zeros
+    return st.stats["host_copy_bytes"]
+
+
+def _sweep(**kw):
+    offload_mod.reset_spill_stats()
+    _assert_bitwise(_grads("pnode", offload="spill", **kw), _grads("pnode"))
+    st = offload_mod.spill_stats()
+    assert st["write_bytes"] > 0
+    return st["host_copy_bytes"]
+
+
+@pytest.mark.parametrize("case,copies", [
+    ("ram_sweep", False),
+    ("lanes_no_padding", False),
+    ("disk_sweep", True),
+    ("padding_lane", True),
+    ("corrupt_write", True),
+    ("missing_row", True),
+])
+def test_host_copy_bytes_by_path(case, copies):
+    """``host_copy_bytes`` reads 0 where the store keeps and returns jax's
+    arrays as they are, and counts the bytes on each path that copies."""
+    copied = {
+        "ram_sweep": lambda: _sweep(),
+        "disk_sweep": lambda: _sweep(snaps_in_ram=0),
+        "lanes_no_padding": lambda: _lanes(("r0", "r1")),
+        "padding_lane": lambda: _lanes(("r0", None)),
+        "corrupt_write": _corrupt_write,
+        "missing_row": _missing_row,
+    }[case]()
+    assert (copied > 0) == copies, copied
+
+
+def test_ram_bytes_count_each_shared_chunk_once():
+    st = offload_mod.make_store("spill")
+    x = jnp.arange(12.0).reshape(4, 3)  # 4 slots, one callback, one chunk
+    tok = jax.jit(lambda t, x: st.write_batch(t, 0, x))(st.init_token(), x)
+    jax.block_until_ready(tok)
+    owners = {id(kept) for kept, _ in st._host.values()}
+    assert len(owners) == 1  # every slot views the one chunk
+    assert st.stats["ram_bytes_peak"] == st._ram_bytes == x.nbytes
+    for slot in range(3):  # the last slot still pins the whole chunk
+        st._drop_slot(slot)
+        assert st._ram_bytes == x.nbytes
+    st._drop_slot(3)
+    assert st._ram_bytes == 0
 
 
 # ---------------------------------------------------------------------------
