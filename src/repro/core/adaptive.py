@@ -9,42 +9,22 @@ reverse pass scans the buffer backward applying the per-stage discrete
 adjoint with each step's own h.
 
 The reverse sweep's cost scales with *accepted* steps, not ``max_steps``:
-each slot's adjoint step sits inside a ``lax.cond`` on ``idx < n_accepted``,
-so slots in the invalid tail of the ring buffer execute the identity branch
-— zero f evaluations — instead of computing a masked-out adjoint step as
-the pre-fusion implementation did.  Measured NFE-B is therefore
-``adjoint_stages('dopri5') * n_accepted`` regardless of ``max_steps``
-(BENCH_3's hot-path section asserts this).
+each slot's adjoint step sits inside a ``lax.cond`` on ``idx <
+n_accepted``, so the invalid tail of the ring runs the identity branch,
+with zero f evaluations (NFE-B = ``adjoint_stages('dopri5') *
+n_accepted``).  Returns (u_final, info); info counts accepted and
+rejected steps.
 
-Returns (u_final, info) where info carries NFE counters (accepted/rejected) —
-these feed the Table-8 benchmark.
-
-mem — the ring buffer allocates max_steps*(N_s+1) state vectors up front
+mem — the ring allocates max_steps*(N_s+1) state vectors up front
 (Table-2 pnode storage at the worst-case step count).  ``offload="spill"``
-(or ``"disk"`` — same callbacks, file-backed payloads) writes accepted
-steps through a ``repro.mem.offload`` store instead: the device carries
-one token scalar plus a SEGMENT-SIZED staging ring, the host side holds
-the checkpoints, and the reverse sweep prefetches them back one
-``offload_segment``-sized chunk per host callback (``store.prefetch``;
-segments whose first slot is past ``n_accepted`` are cond-skipped, so
-host round-trips are O(n_accepted / segment), not O(max_steps)).
-
-The FORWARD sweep is segment-batched too: accepted steps land in a
-device-side ring of ``offload_segment`` slots (rejected attempts
-where-mask to a no-op), and the ring is flushed with ONE ``write_batch``
-callback each time the accepted count crosses a segment boundary, plus
-one trailing flush for the partial last segment — ceil(n_accepted/seg)
-write callbacks total instead of one per *attempted* step (the last O(N)
-callback path; tests/test_hotpath.py asserts the ceil bound).  The
-trailing flush ships the full ring, so slots in [n_accepted,
-ceil(n_accepted/seg)*seg) hold stale ring entries — the reverse sweep
-cond-skips everything past ``n_accepted``, so they are never read.  The
-reverse sweep software-pipelines its reads (``prefetch_issue`` of
-segment k-1 right after segment k's data lands — see
-``repro.mem.offload``), overlapping host/disk I/O with adjoint compute.
-Device-live memory is O(segment) states for any max_steps, with
-identical gradients (rejected steps never reach the store, mirroring the
-paper's observation that they cost the adjoint nothing).
+(or ``"disk"``) writes accepted steps through a ``repro.mem.offload``
+store instead: they land in a device ring of ``offload_segment`` slots
+(rejected attempts where-mask to a no-op), flushed by ONE ``write_batch``
+each time the accepted count crosses a segment boundary, plus a trailing
+flush of the whole ring, whose stale slots past ``n_accepted`` are never
+read.  The reverse is ``repro.core.sweeps.reverse_segments`` with
+``n_accepted`` as its limit: segments past it cost no callback.  Device
+memory is O(segment) states for any max_steps, with identical gradients.
 
 ``fused_stages=True`` lowers the RK stage updates (forward) and per-stage
 adjoint recursion (reverse) through the Pallas ``fused_lincomb`` kernel
@@ -52,6 +32,7 @@ adjoint recursion (reverse) through the Pallas ``fused_lincomb`` kernel
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, NamedTuple
 
@@ -59,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import tree_util as jtu
 
+from repro.core import sweeps
 from repro.core.integrators import (
     PyTree,
     VectorField,
@@ -69,7 +51,7 @@ from repro.core.integrators import (
     tree_stack,
     tree_zeros_like,
 )
-from repro.core.tableaus import DOPRI5, get_tableau
+from repro.core.tableaus import DOPRI5
 from repro.obs.profile import scope
 
 
@@ -102,16 +84,11 @@ def odeint_adaptive(f: VectorField, u0: PyTree, theta: PyTree, *,
                     fused_stages: bool = False,
                     obs=None, fault_plan=None):
     """Adaptive solve from t0 to t1; differentiable (discrete adjoint over
-    accepted steps).  Returns (u_final, AdaptiveInfo).  ``offload="spill"``
-    (or ``"disk"`` for file-backed payloads) replaces the preallocated
-    ring buffer with a host-side checkpoint store: accepted steps batch
-    through a segment-sized staging ring flushed once per
-    ``offload_segment`` accepted steps (default ceil(sqrt(max_steps))),
-    and the reverse sweep prefetches them back one segment per host
-    callback; ``snaps_in_ram`` caps the spill tier's RAM-resident slots
-    (overflow sinks to disk files) and ``offload_dir`` pins the disk
-    files to a caller-owned directory.  ``fused_stages`` selects the
-    Pallas stage-fusion kernels (see module docstring).
+    accepted steps).  Returns (u_final, AdaptiveInfo).  ``offload``
+    ("spill"/"disk"), ``offload_segment`` (default
+    ceil(sqrt(max_steps))), ``snaps_in_ram`` and ``offload_dir`` replace
+    the ring buffer with a host-side store as in ``odeint`` (module
+    docstring); ``fused_stages`` selects the Pallas stage-fusion kernels.
 
     ``obs=`` attaches a ``repro.obs.FlightRecorder``: every *attempted*
     step emits a runtime ``adaptive.step`` event (t, h, error norm,
@@ -135,68 +112,39 @@ def odeint_adaptive(f: VectorField, u0: PyTree, theta: PyTree, *,
     (``err_norm`` NaN, ``accept`` False)."""
     if method != "dopri5":
         raise ValueError("adaptive integration currently supports dopri5")
-    if offload not in (None, "device", "spill", "disk"):
-        raise ValueError(
-            f"unknown offload tier {offload!r} for the adaptive ring "
-            "buffer; one of (None, 'device', 'spill', 'disk')")
-    if offload_segment is not None and offload not in ("spill", "disk"):
-        raise ValueError(
-            "offload_segment only applies to the callback spill/disk "
-            f"tiers; got offload={offload!r}")
-    if snaps_in_ram is not None and offload != "spill":
-        raise ValueError(
-            "snaps_in_ram is the spill tier's RAM/disk split "
-            f"(offload='spill'); got offload={offload!r}")
-    if offload_dir is not None and offload not in ("spill", "disk"):
-        raise ValueError(
-            "offload_dir pins the disk tier's segment files "
-            f"(offload='spill'/'disk'); got offload={offload!r}")
-    if offload in ("spill", "disk") and fault_plan is not None:
-        # tier outage: the scanned ring buffer walks spill -> disk ->
-        # device (the slot-addressed host tier is not scanned-capable)
-        from repro.mem.offload import effective_tier
-        eff = effective_tier(offload, fault_plan, scanned=True, obs=obs)
-        offload = None if eff in (None, "device") else eff
-    store = None
-    segment = 1
-    if offload in ("spill", "disk"):
-        from repro.core.adjoint import _reject_vmap_offload
-        from repro.mem.offload import default_segment, make_store
-        _reject_vmap_offload(u0, theta, "odeint_adaptive")
-        store = make_store(offload, fault_plan=fault_plan,
-                           snaps_in_ram=snaps_in_ram, disk_dir=offload_dir)
-        segment = (int(offload_segment) if offload_segment is not None
-                   else default_segment(int(max_steps)))
-        segment = max(1, min(segment, int(max_steps)))
+    requested = offload
+    store, segment, offload = sweeps.resolve_offload(
+        "odeint_adaptive", u0, theta, tier=offload, policy="pnode",
+        n_steps=int(max_steps), segment=offload_segment,
+        snaps_in_ram=snaps_in_ram, offload_dir=offload_dir,
+        fault_plan=fault_plan, batched=False, obs=obs)
+    if store is None:
+        segment = 1
+        if requested in ("spill", "disk"):  # degraded past the scanned tiers
+            offload = None
     h_init = float(h0) if h0 is not None else (float(t1) - float(t0)) / 100.0
     if obs is not None:
-        if store is not None:
-            store.bind_obs(obs)
         obs.record("adaptive.solve", method=method, t0=float(t0),
                    t1=float(t1), rtol=float(rtol), atol=float(atol),
                    max_steps=int(max_steps), h0=h_init,
                    offload=offload, segment=segment,
                    fused=bool(fused_stages))
-    u_final, info = _odeint_adaptive(f, float(t0), float(t1), float(rtol),
-                                     float(atol), int(max_steps),
-                                     float(h_init), store, segment,
-                                     bool(fused_stages), obs, fault_plan,
-                                     u0, theta)
-    return u_final, info
+    return _odeint_adaptive(f, float(t0), float(t1), float(rtol),
+                            float(atol), int(max_steps), float(h_init),
+                            store, segment, bool(fused_stages), obs,
+                            fault_plan, u0, theta)
 
 
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _odeint_adaptive(f, t0, t1, rtol, atol, max_steps, h0, store, segment,
                      fused, obs, fault, u0, theta):
-    out, _res = _adaptive_fwd_solve(f, t0, t1, rtol, atol, max_steps, h0,
-                                    store, segment, fused, u0, theta,
-                                    obs=obs, fault=fault)
-    return out
+    return _adaptive_fwd_solve(f, t0, t1, rtol, atol, max_steps, h0, store,
+                               segment, fused, obs, fault, u0, theta)[0]
 
 
 def _adaptive_fwd_solve(f, t0, t1, rtol, atol, max_steps, h0, store, segment,
-                        fused, u0, theta, obs=None, fault=None):
+                        fused, obs, fault, u0, theta):
     tab = DOPRI5
     s = tab.num_stages
     order = tab.order
@@ -339,101 +287,39 @@ def _adaptive_fwd_solve(f, t0, t1, rtol, atol, max_steps, h0, store, segment,
     return (u_f, info), (bufs, n_acc, theta)
 
 
-@scope("adaptive/fwd")
-def _odeint_adaptive_fwd(f, t0, t1, rtol, atol, max_steps, h0, store,
-                         segment, fused, obs, fault, u0, theta):
-    out, res = _adaptive_fwd_solve(f, t0, t1, rtol, atol, max_steps, h0,
-                                   store, segment, fused, u0, theta,
-                                   obs=obs, fault=fault)
-    return out, res
-
-
 @scope("adaptive/bwd")
 def _odeint_adaptive_bwd(f, t0, t1, rtol, atol, max_steps, h0, store,
                          segment, fused, obs, fault, res, g):
-    tab = DOPRI5
     if obs is not None:
         obs.record("adaptive.adjoint", max_steps=max_steps,
                    segment=segment,
                    tier=store.tier if store is not None else "device")
     bufs, n_acc, theta = res
     g_u, _g_info = g  # ignore cotangents of the counters
-    spill = store is not None
-
-    def adjoint_one(lam, mu, u_n, k_n, h_n, t_n):
-        lam2, th_bar = rk_adjoint_step(f, tab, u_n, k_n, theta, t_n, h_n,
-                                       lam, fused=fused)
-        return lam2, tree_add(mu, th_bar)
-
-    if not spill:
-        sb, kb, hb, tb = bufs
-
-        def body(carry, idx):
-            # cond (not where-masking): the invalid tail of the ring buffer
-            # takes the identity branch, so reverse-sweep f evaluations
-            # scale with n_accepted, not max_steps
-            def do(c):
-                lam, mu = c
-                u_n = jtu.tree_map(lambda b: b[idx], sb)
-                k_n = jtu.tree_map(lambda b: b[idx], kb)
-                return adjoint_one(lam, mu, u_n, k_n, hb[idx], tb[idx])
-
-            return jax.lax.cond(idx < n_acc, do, lambda c: c, carry), None
-
-        (lam, mu), _ = jax.lax.scan(
-            body, (g_u, tree_zeros_like(theta)),
-            jnp.arange(max_steps), reverse=True)
-        return lam, mu
-
-    # spill tier: segment-prefetched reverse sweep — one host callback per
-    # offload_segment slots, and segments entirely past n_accepted are
-    # cond-skipped (no callback, no f evaluations)
-    seg = max(1, min(segment, max_steps))
-    n_full, remainder = divmod(max_steps, seg)
-    tok = bufs
-
-    def run_segment_bwd(carry, base, m):
-        def proc(args):
-            lam, mu, tok = args
-            tok2, staged = store.prefetch(tok, base, m)  # ONE callback
-            # software pipelining: queue the background gather of the next
-            # (earlier) segment while this one's adjoint computes; base <
-            # n_acc here, so nb < n_acc holds whenever nb >= 0 and the
-            # issued segment is never a skipped one
-            nb = base - seg
-            tok2 = jax.lax.cond(
-                nb >= 0,
-                lambda t_: store.prefetch_issue(t_, jnp.maximum(nb, 0),
-                                                seg),
-                lambda t_: t_, tok2)
-
-            def step(c, i):
-                idx = base + i
-
-                def do(c2):
-                    lam, mu = c2
-                    u_n, k_n, h_n, t_n = jtu.tree_map(lambda b: b[i], staged)
-                    return adjoint_one(lam, mu, u_n, k_n, h_n, t_n)
-
-                return jax.lax.cond(idx < n_acc, do, lambda c2: c2, c), None
-
-            (lam, mu), _ = jax.lax.scan(step, (lam, mu), jnp.arange(m),
-                                        reverse=True)
-            return lam, mu, tok2
-
-        return jax.lax.cond(base < n_acc, proc, lambda a: a, carry)
-
-    carry = (g_u, tree_zeros_like(theta), tok)
-    if remainder:  # trailing partial segment holds the highest slots
-        carry = run_segment_bwd(carry, jnp.asarray(n_full * seg), remainder)
-    if n_full:
-        def seg_body(c, s_idx):
-            return run_segment_bwd(c, s_idx * seg, seg), None
-
-        carry, _ = jax.lax.scan(seg_body, carry, jnp.arange(n_full),
-                                reverse=True)
-    lam, mu, _tok = carry
+    st = _Accepted(f, fused)
+    if store is None:  # the ring; its tail past n_accepted is skipped
+        return sweeps.adjoint_scan(st, theta, g_u, tree_zeros_like(theta),
+                                   bufs, None, None, max_steps, sliced=True,
+                                   limit=n_acc)
+    lam, mu, _, _ = sweeps.reverse_segments(
+        st, theta, store, max(1, min(segment, max_steps)), max_steps, g_u,
+        tree_zeros_like(theta), None, bufs, limit=n_acc)
     return lam, mu
 
 
-_odeint_adaptive.defvjp(_odeint_adaptive_fwd, _odeint_adaptive_bwd)
+@dataclasses.dataclass(frozen=True, eq=False)
+class _Accepted(sweeps.Stepper):
+    """The adjoint of an accepted dopri5 step: its checkpoint carries its
+    own step size and time."""
+
+    f: Any
+    fused: bool = False
+
+    def adjoint(self, ckpt, u_next, theta, n, lam):
+        u, stages, h, t = ckpt
+        return rk_adjoint_step(self.f, DOPRI5, u, stages, theta, t, h, lam,
+                               fused=self.fused)
+
+
+_odeint_adaptive.defvjp(scope("adaptive/fwd")(_adaptive_fwd_solve),
+                        _odeint_adaptive_bwd)

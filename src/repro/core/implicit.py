@@ -22,52 +22,36 @@ the paper's key memory argument for implicit schemes AND what makes
 checkpoint spacing cheap here: a checkpoint is one *converged state*
 vector, the Newton/GMRES iterates are never stored.
 
-Checkpoint policies (``adjoint=``), mirroring ``core/adjoint.py``:
+Checkpoint policies (``adjoint=``), mirroring ``core/adjoint.py`` and run
+by the same sweeps (``repro.core.sweeps``, with ``ThetaStepper``):
 
-  pnode     store every converged state u_0..u_{N-1} (+ u_final); the
-            reverse pass solves one transposed linear system per step with
-            zero recomputation.  Under ``offload="spill"`` the states are
-            segment-batched through the host-callback ``SpillStore``
-            (one ``write_batch``/``prefetch`` round-trip per
-            ceil(sqrt(N_t))-step segment), so device-live memory is
-            O(segment) states regardless of N_t — and, unlike the explicit
-            scanned spill path, this one is **vmap-compatible**: the store
-            callbacks are vectorized (``vmap_method="broadcast_all"``), a
-            single host round-trip carries the whole batch and each batch
-            element occupies its own block of the spilled slot (the
-            per-batch-element key scheme; see ``repro.mem.offload``).
-  revolve   binomial (Prop. 2) checkpoint schedule over states only:
-            ``ncheck`` slots, segments re-advanced by re-running the Newton
-            solve — recomputation trades against memory exactly as in the
-            explicit case, except a slot costs S bytes, not (N_s+1)S.
-            Slots live in a ``CheckpointStore`` tier
-            (device / pinned-host / callback-spill).
-  revolve2  scanned two-level variant (bounded compiled liveness): boundary
-            states in the store, each segment re-advanced once and
-            adjointed under ``lax.scan``.
-  auto      delegate the (policy, ncheck, offload) choice to
-            ``repro.mem.planner.plan_odeint`` under ``mem_budget=<bytes>``
-            (the implicit cost model: per-step recompute cost
-            newton_iters*(gmres_iters+2)+1 f evaluations, NFE-B
-            gmres_iters+2 per adjoint solve).
+  pnode     every converged state; one transposed linear solve per reverse
+            step, no recomputation.  ``offload="spill"``/``"disk"``
+            streams them through the segment-batched store, and that path
+            composes with ``jax.vmap``: one host round-trip per segment
+            carries the whole batch, each element in its own block of the
+            slot (see ``repro.mem.offload``).
+  revolve   the Prop. 2 schedule over states only (a slot costs S bytes,
+            not (N_s+1)S); segments are re-advanced by re-running Newton.
+  revolve2  boundary states, each segment re-advanced once under a scan.
+  auto      ``repro.mem.planner.plan_odeint`` picks (policy, ncheck,
+            offload) under ``mem_budget=<bytes>`` with the implicit cost
+            model (recompute newton_iters*(gmres_iters+2)+1 f evaluations
+            a step, NFE-B gmres_iters+2 per adjoint solve).
 
 ``adjoint="naive"`` (AD through the solver) is impossible by construction:
 Newton/GMRES run in ``while_loop``s that have no reverse rule — the
 paper's motivating limitation.  The AD-through-a-dense-unrolled-Newton
-oracle in tests/test_reverse_accuracy.py is the exactness reference.
+oracle in tests/test_reverse_accuracy_implicit.py is the exactness
+reference.
 
 Convergence reporting: every path threads a converged flag and the final
-Newton residual out of the step loop; ``odeint_implicit(...,
-return_stats=True)`` returns ``(u_final, ImplicitStats)`` where
-``stats.diverged`` is True if ANY step exhausted ``newton_iters`` with
-residual > ``newton_tol`` (instead of silently returning garbage states
-and gradients), ``stats.max_residual`` is the worst final residual and
-``stats.newton_iters`` the total iteration count (the measured forward
-NFE driver).
+Newton residual out of the step loop into ``ImplicitStats``
+(``return_stats=True``).
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -75,8 +59,8 @@ import jax.numpy as jnp
 from jax import tree_util as jtu
 from jax.scipy.sparse.linalg import gmres
 
-from repro.obs.profile import scope
 from repro.core import revolve as revolve_mod
+from repro.core import sweeps
 from repro.core.integrators import (
     PyTree,
     VectorField,
@@ -85,27 +69,19 @@ from repro.core.integrators import (
     tree_norm,
     tree_scale,
     tree_sub,
-    tree_zeros_like,
 )
 
 IMPLICIT_METHODS = ("beuler", "cn")
 IMPLICIT_POLICIES = ("pnode", "revolve", "revolve2")
 
 
-def _mass_apply(mass):
-    if mass is None:
-        return lambda u: u
-    if callable(mass):
-        return mass
-    return lambda u: jtu.tree_map(lambda x: mass @ x, u)
-
-
-def _mass_apply_t(mass):
+def _mass_apply(mass, transpose=False):
     if mass is None:
         return lambda u: u
     if callable(mass):  # caller supplies a self-adjoint / explicit transpose
         return mass
-    return lambda u: jtu.tree_map(lambda x: mass.T @ x, u)
+    return lambda u: jtu.tree_map(
+        lambda x: (mass.T if transpose else mass) @ x, u)
 
 
 def _theta_of(method: str) -> float:
@@ -155,10 +131,9 @@ class RescueConfig(NamedTuple):
 
 
 class _SolverConfig(NamedTuple):
-    """Static (hashable) solver knobs — a single nondiff custom_vjp arg.
-    ``rescue``/``fault``/``resilient`` default off: dormant configs build
-    the exact pre-PR-8 trace (``_step`` stages no gates, the spill
-    residuals carry no boundary states)."""
+    """Static solver knobs.  ``rescue``/``fault``/``resilient`` default
+    off: then ``_step`` stages no gates and the spill residuals carry no
+    boundary states."""
     theta: float
     newton_iters: int
     newton_tol: float
@@ -242,7 +217,7 @@ def implicit_adjoint_step(f: VectorField, u_n: PyTree, u_next: PyTree,
                           gmres_tol: float = 1e-10, mass=None):
     """One reverse step of the theta-method discrete adjoint (eq. 13)."""
     t_next = t_n + h
-    apply_mt = _mass_apply_t(mass)
+    apply_mt = _mass_apply(mass, transpose=True)
 
     # transposed linear solve: (M - h*theta*f_u(u_next))^T lam_s = lam
     _, vjp_next = jax.vjp(lambda uu, th: f(uu, th, t_next), u_next, theta_p)
@@ -363,11 +338,6 @@ def _step(f, cfg: _SolverConfig, u, theta_p, t_n, h, idx=None):
                          jnp.asarray(0 if idx is None else idx))
 
 
-def _adjoint_step(f, cfg: _SolverConfig, u_n, u_next, theta_p, t_n, h, lam):
-    return implicit_adjoint_step(f, u_n, u_next, theta_p, t_n, h, cfg.theta,
-                                 lam, cfg.gmres_iters, cfg.gmres_tol)
-
-
 # ---------------------------------------------------------------------------
 # Table-2-style accounting for the implicit family (the planner's model)
 # ---------------------------------------------------------------------------
@@ -407,8 +377,7 @@ def implicit_nfe_backward(n_steps: int, adjoint: str,
     if adjoint == "revolve":
         return revolve_mod.optimal_extra_steps(n_steps, ncheck) * stepc + adj
     if adjoint == "revolve2":
-        n_bound = len(revolve_mod.sweep_checkpoint_positions(
-            n_steps, ncheck)) + 1
+        n_bound = len(sweeps.segment_bounds(n_steps, ncheck))
         return (n_steps - n_bound) * stepc + adj
     raise ValueError(adjoint)
 
@@ -423,8 +392,8 @@ def implicit_checkpoint_floats(n_steps: int, adjoint: str, state_size: int,
     if adjoint == "revolve":
         return (ncheck + 1) * state_size
     if adjoint == "revolve2":
-        bounds = [0] + revolve_mod.sweep_checkpoint_positions(n_steps, ncheck)
-        seg = max(b - a for a, b in zip(bounds, bounds[1:] + [n_steps]))
+        bounds = sweeps.segment_bounds(n_steps, ncheck)
+        seg = max(b - a for a, b in bounds)
         return (len(bounds) + seg + 1) * state_size
     raise ValueError(adjoint)
 
@@ -449,21 +418,16 @@ def odeint_implicit(f: VectorField, u0: PyTree, theta_p: PyTree, *, dt: float,
                     resilient: bool = False) -> PyTree:
     """Fixed-step implicit theta-method solve with a discrete adjoint.
 
-    ``adjoint`` selects the checkpoint policy (``pnode`` dense states /
-    ``revolve`` / ``revolve2``; ``auto`` + ``mem_budget=<bytes>`` delegates
-    to the ``repro.mem`` planner, which knows the implicit cost model).
-    ``offload`` routes checkpoints through a ``repro.mem.offload`` store
-    tier exactly like the explicit ``odeint`` (including the ``disk``
-    tier and the ``snaps_in_ram``/``offload_dir`` RAM/disk split knobs);
-    gradients are bitwise-identical across tiers.  ``return_stats=True`` returns
-    ``(u_final, ImplicitStats)`` so Newton/GMRES non-convergence surfaces
-    as ``stats.diverged`` instead of silently wrong states/gradients.
-
-    The scanned ``pnode`` + ``offload="spill"`` path supports ``jax.vmap``
-    (batched stiff ensembles under a byte budget): the spill callbacks are
-    vectorized, one host round-trip per segment carries the whole batch.
-    The slot-addressed revolve tiers reject vmap up front like the
-    explicit path does.
+    ``adjoint`` selects the checkpoint policy (module docstring; ``auto``
+    + ``mem_budget=<bytes>`` delegates to the ``repro.mem`` planner).
+    ``offload``, ``offload_segment``, ``snaps_in_ram`` and
+    ``offload_dir`` route checkpoints through a ``repro.mem.offload``
+    store exactly as in ``odeint``; gradients are bitwise-identical across
+    tiers, and the scanned pnode spill/disk path composes with
+    ``jax.vmap`` (slot-addressed revolve tiers reject it up front).
+    ``return_stats=True`` returns ``(u_final, ImplicitStats)`` so
+    Newton/GMRES non-convergence surfaces as ``stats.diverged`` instead of
+    silently wrong states/gradients.
 
     ``obs=`` attaches a ``repro.obs.FlightRecorder``: every sweep emits
     a runtime ``implicit.steps`` event carrying the stacked per-step
@@ -474,8 +438,8 @@ def odeint_implicit(f: VectorField, u0: PyTree, theta_p: PyTree, *, dt: float,
     traffic.  Debug-effect taps only — gradients are bitwise-identical
     to ``obs=None``, which traces nothing extra (zero overhead off).
 
-    Fault tolerance (PR 8; all three knobs default OFF and stage zero
-    extra ops when off):
+    Fault tolerance (all three knobs default OFF and stage zero extra ops
+    when off):
 
     ``rescue=`` a ``RescueConfig`` (or ``True`` for the defaults) turns on
     in-step divergence rescue: a failed step (Newton cap exhausted, or a
@@ -547,41 +511,6 @@ def odeint_implicit(f: VectorField, u0: PyTree, theta_p: PyTree, *, dt: float,
         raise ValueError(f"unknown implicit adjoint policy {adjoint!r}; one "
                          f"of {IMPLICIT_POLICIES} (or 'auto' with "
                          "mem_budget)")
-    from repro.core.adjoint import _OFFLOAD_TIERS, _validate_ncheck
-    if offload not in _OFFLOAD_TIERS:
-        raise ValueError(f"unknown offload tier {offload!r}; one of "
-                         f"{_OFFLOAD_TIERS}")
-    offloaded = offload in ("host", "spill", "disk")
-    if offload_segment is not None:
-        if offload not in ("spill", "disk"):
-            raise ValueError(
-                "offload_segment only applies to the callback spill tiers "
-                f"(offload='spill'/'disk'); got offload={offload!r}")
-        if adjoint != "pnode":
-            raise ValueError(
-                "offload_segment only applies to the scanned pnode sweep "
-                f"(adjoint='pnode'); adjoint={adjoint!r} checkpoints are "
-                "slot-addressed at trace time")
-        offload_segment = int(offload_segment)
-        if offload_segment < 1:
-            raise ValueError(
-                f"offload_segment must be >= 1, got {offload_segment}")
-    if snaps_in_ram is not None:
-        if offload != "spill":
-            raise ValueError(
-                "snaps_in_ram is the spill tier's RAM/disk split "
-                "(offload='spill'; offload='disk' is the snaps_in_ram=0 "
-                f"corner); got offload={offload!r}")
-        snaps_in_ram = int(snaps_in_ram)
-        if snaps_in_ram < 0:
-            raise ValueError(
-                f"snaps_in_ram must be >= 0, got {snaps_in_ram}")
-    if offload_dir is not None and offload not in ("spill", "disk"):
-        raise ValueError(
-            "offload_dir pins the disk tier's segment files "
-            "(offload='spill'/'disk'); got offload="
-            f"{offload!r}")
-
     if rescue is True:
         rescue = RescueConfig()
     if rescue is not None and not isinstance(rescue, RescueConfig):
@@ -594,79 +523,34 @@ def odeint_implicit(f: VectorField, u0: PyTree, theta_p: PyTree, *, dt: float,
             "to the scanned spill paths (adjoint='pnode', "
             f"offload='spill'/'disk'); got adjoint={adjoint!r}, "
             f"offload={offload!r}")
-    if fault_plan is not None and offloaded:
-        # tier outage in the plan: walk the degradation ladder BEFORE the
-        # store is built, so the solve runs on a healthy tier
-        from repro.mem.offload import effective_tier
-        eff = effective_tier(offload, fault_plan,
-                             scanned=(adjoint == "pnode"), obs=obs)
-        if eff != offload:
-            offload = eff
-            offloaded = offload in ("host", "spill", "disk")
-            if offload not in ("spill", "disk"):
-                offload_segment = None
-                snaps_in_ram = None
-            resilient = resilient and offload in ("spill", "disk")
+    if adjoint in ("revolve", "revolve2"):
+        ncheck = sweeps.validate_ncheck(adjoint, ncheck, n_steps)
+    store, segment, offload = sweeps.resolve_offload(
+        f"odeint_implicit(adjoint={adjoint!r})", u0, theta_p, tier=offload,
+        policy=adjoint, n_steps=n_steps, segment=offload_segment,
+        snaps_in_ram=snaps_in_ram, offload_dir=offload_dir,
+        fault_plan=fault_plan, integrity=bool(resilient), obs=obs)
+    resilient = bool(resilient) and offload in ("spill", "disk")
 
     cfg = _SolverConfig(theta, int(newton_iters), float(newton_tol),
                         int(gmres_iters), float(gmres_tol),
                         rescue=rescue, fault=fault_plan,
-                        resilient=bool(resilient))
+                        resilient=resilient)
     t0, dt = float(t0), float(dt)
     if obs is not None:
-        extra = {}
-        if rescue is not None:
-            extra["rescue"] = True
-        if fault_plan is not None:
-            extra["faulted"] = True
-        if resilient:
-            extra["resilient"] = True
+        extra = {k: True for k, on in (("rescue", rescue is not None),
+                                       ("faulted", fault_plan is not None),
+                                       ("resilient", resilient)) if on}
         obs.record("implicit.solve", method=method, adjoint=adjoint,
                    n_steps=n_steps, dt=dt, t0=t0,
                    ncheck=None if ncheck is None else int(ncheck),
                    offload=offload, newton_iters=cfg.newton_iters,
                    gmres_iters=cfg.gmres_iters, planned=from_auto, **extra)
 
-    if adjoint in ("revolve", "revolve2"):
-        ncheck = _validate_ncheck(adjoint, ncheck, n_steps)
-        if offloaded:
-            # slot-addressed stores see one logical slot per batch — the
-            # same aliasing hazard the explicit path rejects up front
-            from repro.core.adjoint import _reject_vmap_offload
-            _reject_vmap_offload(u0, theta_p,
-                                 f"odeint_implicit(adjoint={adjoint!r})")
-        from repro.mem.offload import make_store  # deferred: import cycle
-        store = make_store(offload, fault_plan=fault_plan,
-                           snaps_in_ram=snaps_in_ram, disk_dir=offload_dir)
-        if obs is not None:
-            store.bind_obs(obs)
-        impl = _imp_revolve if adjoint == "revolve" else _imp_revolve2
-        u_final, stats = impl(f, cfg, t0, dt, n_steps, ncheck, store, u0,
-                              theta_p)
-    elif offloaded:  # pnode
-        if offload == "host":
-            raise ValueError(
-                "offload='host' applies to trace-time checkpoint sites "
-                "(revolve/revolve2); the scanned pnode sweep offloads "
-                "through offload='spill'")
-        from repro.mem.offload import (batch_scale, default_segment,
-                                       make_store)
-        segment = (offload_segment if offload_segment is not None
-                   else default_segment(n_steps))
-        store = make_store(offload, fault_plan=fault_plan,
-                           integrity=bool(resilient),
-                           snaps_in_ram=snaps_in_ram, disk_dir=offload_dir)
-        if obs is not None:
-            store.bind_obs(obs)
-        # mapped axes are only visible HERE (as BatchTracers on the args);
-        # the custom_vjp fwd is retraced at logical shapes, so the store's
-        # payload-cap chunking needs the batch factor handed to it
-        store.payload_scale = batch_scale((u0, theta_p))
-        u_final, stats = _imp_spill(f, cfg, t0, dt, n_steps, store,
-                                    min(segment, n_steps), u0, theta_p)
-    else:
-        u_final, stats = _imp_dense(f, cfg, t0, dt, n_steps, obs, u0,
-                                    theta_p)
+    u_final, stats = sweeps.solve(ThetaStepper(f, cfg, t0, dt, obs),
+                                  adjoint, n_steps, u0, theta_p,
+                                  ncheck=ncheck, store=store,
+                                  segment=segment)
     return (u_final, stats) if return_stats else u_final
 
 
@@ -693,402 +577,126 @@ def _odeint_implicit_mass(f, mass, t0, dt, n_steps, theta, newton_iters,
 
 
 # ---------------------------------------------------------------------------
-# dense pnode: every converged state rides the custom_vjp residuals
+# the implicit stepper of the checkpointed sweeps (core.sweeps)
 # ---------------------------------------------------------------------------
 
-def _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p, save_states, base=0,
-               obs=None, obs_kind="implicit.steps"):
-    track_rescue = cfg.rescue is not None or cfg.fault is not None
+@dataclasses.dataclass(frozen=True, eq=False)
+class ThetaStepper(sweeps.Stepper):
+    """Theta-method steps: a checkpoint is one converged state, the
+    adjoint step reads u_{n+1}, and a re-advance re-runs Newton — bitwise
+    the forward's states, faults and rescues included, since every step's
+    time is t0 + dt*(base+n) and its fault gates key on that index.  With
+    ``obs``, a sweep emits its stacked ``StepInfo``s as one top-level
+    ``implicit.steps`` (re-advances: ``implicit.recompute``) tap: a
+    per-step tap in a scan is dropped in custom_vjp fwd rules on jax
+    0.4.37 (``repro.obs.trace``).  Taps feed nothing."""
 
-    def body(carry, n):
-        u, stats = carry
-        # t as t0 + dt*(base+n) everywhere (not (t0+dt*base) + dt*n) so a
-        # recomputed segment's times — hence its states — are bitwise the
-        # forward sweep's
-        t_n = t0 + dt * (base + n)
-        u_next, info, resc = _step(f, cfg, u, theta_p, t_n, dt, base + n)
-        ys = u if save_states else None
-        if obs is not None:
-            ys = (ys, info, resc if track_rescue else None)
-        return (u_next, _stats_merge(stats, info, resc)), ys
+    f: Callable
+    cfg: _SolverConfig
+    t0: float
+    dt: float
+    obs: Any = None
 
-    (u_final, stats), ys = jax.lax.scan(body, (u0, _stats_zero()),
-                                        jnp.arange(n_steps))
-    if obs is not None:
+    scopes = {"dense": "implicit", "revolve": "imp_revolve",
+              "revolve2": "imp_revolve2", "segmented": "imp_spill"}
+    needs_next = True
+
+    @property
+    def resilient(self):
+        return self.cfg.resilient
+
+    def _step(self, u, theta, base, n):
+        return _step(self.f, self.cfg, u, theta,
+                     self.t0 + self.dt * (base + n), self.dt, base + n)
+
+    def shifted(self, states, u_end):
+        return jtu.tree_map(
+            lambda s, ue: jnp.concatenate([s[1:], ue[None]], axis=0),
+            states, u_end)
+
+    def _emit(self, kind, base, infos):
+        self.obs.emit(kind, base=jnp.asarray(base), iters=infos.iters,
+                      residual=infos.residual, converged=infos.converged)
+
+    def aux0(self):
+        return _stats_zero()
+
+    def out(self, u, aux):
+        return u, aux
+
+    def cotangent(self, ct):
+        return ct[0]  # the stats output is non-differentiable
+
+    def solve(self, u, theta, base, m, save=False, recompute=False):
+        track_rescue = (self.cfg.rescue is not None
+                        or self.cfg.fault is not None)
+
+        def body(carry, n):
+            u, stats = carry
+            u_next, info, resc = self._step(u, theta, base, n)
+            ys = u if save else None
+            if self.obs is not None:
+                ys = (ys, info, resc if track_rescue else None)
+            return (u_next, _stats_merge(stats, info, resc)), ys
+
+        (u, stats), ys = jax.lax.scan(body, (u, _stats_zero()),
+                                      jnp.arange(m))
+        if self.obs is None:
+            return u, stats, ys
         states, infos, rescs = ys
-        # ONE stacked debug-effect tap at the top level of the rule: a
-        # per-step tap inside the scan body would be silently dropped in
-        # custom_vjp fwd rules on jax 0.4.37 (scan-in-fwd effects; see
-        # repro.obs.trace docstring), the top-level tap on the stacked
-        # StepInfo is not.  Nothing feeds the computation, so numerics
-        # are unchanged.
-        obs.emit(obs_kind, base=jnp.asarray(base), iters=infos.iters,
-                 residual=infos.residual, converged=infos.converged)
+        self._emit("implicit.recompute" if recompute else "implicit.steps",
+                   base, infos)
         if track_rescue:  # separate stream: dormant event logs unchanged
-            obs.emit("implicit.rescue", base=jnp.asarray(base),
-                     rescued=rescs)
-    else:
-        states = ys
-    return u_final, stats, states
+            self.obs.emit("implicit.rescue", base=jnp.asarray(base),
+                          rescued=rescs)
+        return u, stats, states
 
+    def step(self, u, aux, theta, base, i):
+        u_next, info, resc = self._step(u, theta, base, i)
+        return (u_next, _stats_merge(aux, info, resc), u,
+                info if self.obs is not None else None)
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
-def _imp_dense(f, cfg, t0, dt, n_steps, obs, u0, theta_p):
-    u_final, stats, _ = _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p,
-                                   save_states=False, obs=obs)
-    return u_final, stats
+    def advance(self, u, aux, theta, start, m, recompute=False):
+        if m <= 0:
+            return u, aux
 
-
-@scope("implicit/fwd")
-def _imp_dense_fwd(f, cfg, t0, dt, n_steps, obs, u0, theta_p):
-    u_final, stats, states = _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p,
-                                        save_states=True, obs=obs)
-    return (u_final, stats), (states, u_final, theta_p)
-
-
-@scope("implicit/bwd")
-def _imp_dense_bwd(f, cfg, t0, dt, n_steps, obs, res, ct):
-    g, _ = ct  # the stats output is non-differentiable; drop its cotangent
-    states, u_final, theta_p = res
-
-    # u_next for step n is states[n+1] (or u_final for the last step)
-    u_nexts = jtu.tree_map(
-        lambda s, uf: jnp.concatenate([s[1:], uf[None]], axis=0), states,
-        u_final)
-
-    def body(carry, inp):
-        lam, mu = carry
-        u_n, u_next, n = inp
-        t_n = t0 + dt * n
-        lam, th_bar = _adjoint_step(f, cfg, u_n, u_next, theta_p, t_n, dt,
-                                    lam)
-        return (lam, tree_add(mu, th_bar)), None
-
-    (lam, mu), _ = jax.lax.scan(
-        body, (g, tree_zeros_like(theta_p)),
-        (states, u_nexts, jnp.arange(n_steps)), reverse=True)
-    return lam, mu
-
-
-_imp_dense.defvjp(_imp_dense_fwd, _imp_dense_bwd)
-
-
-# ---------------------------------------------------------------------------
-# revolve: Prop-2 schedule over converged states, Newton re-advance between
-# checkpoints, slots in a CheckpointStore tier
-# ---------------------------------------------------------------------------
-
-def _imp_advance(f, cfg, u, theta_p, start_idx, m, t0, dt, stats=None,
-                 obs=None, obs_kind="implicit.steps"):
-    """Re-run m implicit steps from u (step indices start_idx..start_idx+m-1)
-    — bitwise-identical to the forward sweep's states since the op sequence
-    is the same.  Stats aggregation is optional (the reverse-pass advances
-    drop it: their convergence is the forward's, already reported)."""
-    if m <= 0:
-        return (u, stats) if stats is not None else u
-
-    track = stats is not None
-
-    def body(carry, k):
-        u, st = carry
-        t = t0 + dt * (start_idx + k)
-        u, info, resc = _step(f, cfg, u, theta_p, t, dt, start_idx + k)
-        return (u, _stats_merge(st, info, resc) if track else st), \
-            (info if obs is not None else None)
-
-    (u, stats), infos = jax.lax.scan(body, (u, stats), jnp.arange(m))
-    if obs is not None:  # stacked top-level tap (see _imp_solve)
-        obs.emit(obs_kind, base=jnp.asarray(start_idx), iters=infos.iters,
-                 residual=infos.residual, converged=infos.converged)
-    return (u, stats) if track else u
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6))
-def _imp_revolve(f, cfg, t0, dt, n_steps, ncheck, store, u0, theta_p):
-    u_final, stats, _ = _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p,
-                                   save_states=False, obs=store._obs)
-    return u_final, stats
-
-
-@scope("imp_revolve/fwd")
-def _imp_revolve_fwd(f, cfg, t0, dt, n_steps, ncheck, store, u0, theta_p):
-    positions = [0] + revolve_mod.sweep_checkpoint_positions(n_steps, ncheck)
-    bounds = positions + [n_steps]
-    u, stats = u0, _stats_zero()
-    for a, b in zip(bounds[:-1], bounds[1:]):
-        store.put(a, u)
-        u, stats = _imp_advance(f, cfg, u, theta_p, a, b - a, t0, dt, stats,
-                                obs=store._obs)
-    return (u, stats), (store.pack(), u, theta_p)
-
-
-@scope("imp_revolve/bwd")
-def _imp_revolve_bwd(f, cfg, t0, dt, n_steps, ncheck, store, res, ct):
-    g, _ = ct
-    ckpt_res, u_final, theta_p = res
-    positions = [0] + revolve_mod.sweep_checkpoint_positions(n_steps, ncheck)
-    store.unpack(ckpt_res, positions)
-
-    lam = g
-    mu = tree_zeros_like(theta_p)
-    # the schedule adjoints steps in strictly decreasing order, so u_{n+1}
-    # for the step about to be adjointed is always the previous adjoint's
-    # checkpoint (u_final initially) — no stage storage needed at all
-    u_next = u_final
-    for act in revolve_mod.reverse_schedule(n_steps, ncheck):
-        kind = act[0]
-        if kind == "advance":
-            _, start, m = act
-            u = store.get(start)
-            u = _imp_advance(f, cfg, u, theta_p, start, m, t0, dt,
-                             obs=store._obs, obs_kind="implicit.recompute")
-            store.put(start + m, u)
-        elif kind == "adjoint":
-            _, idx = act
-            u_i = store.get(idx)
-            store.free(idx)
-            t_i = t0 + dt * idx
-            lam, th_bar = _adjoint_step(f, cfg, u_i, u_next, theta_p, t_i,
-                                        dt, lam)
-            mu = tree_add(mu, th_bar)
-            u_next = u_i
-            # trace-time-unrolled chain: serialize so XLA cannot keep every
-            # step's theta-sized gradients live at once (see explicit path)
-            lam, mu = jax.lax.optimization_barrier((lam, mu))
-        elif kind == "free":
-            store.free(act[1])
-        else:  # pragma: no cover
-            raise ValueError(act)
-    return lam, mu
-
-
-_imp_revolve.defvjp(_imp_revolve_fwd, _imp_revolve_bwd)
-
-
-# ---------------------------------------------------------------------------
-# revolve2: boundary states + scanned per-segment re-advance/adjoint
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6))
-def _imp_revolve2(f, cfg, t0, dt, n_steps, ncheck, store, u0, theta_p):
-    u_final, stats, _ = _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p,
-                                   save_states=False, obs=store._obs)
-    return u_final, stats
-
-
-@scope("imp_revolve2/fwd")
-def _imp_revolve2_fwd(f, cfg, t0, dt, n_steps, ncheck, store, u0, theta_p):
-    from repro.core.adjoint import _segment_bounds
-    u, stats = u0, _stats_zero()
-    for a, b in _segment_bounds(n_steps, ncheck):
-        store.put(a, u)
-        u, stats = _imp_advance(f, cfg, u, theta_p, a, b - a, t0, dt, stats,
-                                obs=store._obs)
-    return (u, stats), (store.pack(), theta_p)
-
-
-@scope("imp_revolve2/bwd")
-def _imp_revolve2_bwd(f, cfg, t0, dt, n_steps, ncheck, store, res, ct):
-    g, _ = ct
-    ckpt_res, theta_p = res
-    from repro.core.adjoint import _segment_bounds
-    bounds = _segment_bounds(n_steps, ncheck)
-    store.unpack(ckpt_res, [a for a, _ in bounds])
-
-    lam = g
-    mu = tree_zeros_like(theta_p)
-    for a, b in reversed(bounds):
-        m = b - a
-        u_a = store.get(a)
-        store.free(a)
-        # re-advance the segment, saving states (scan); the recomputed
-        # segment end is bitwise the forward's u_b
-        u_b, _, states = _imp_solve(f, cfg, t0, dt, m, u_a, theta_p,
-                                    save_states=True, base=a,
-                                    obs=store._obs,
-                                    obs_kind="implicit.recompute")
-        u_nexts = jtu.tree_map(
-            lambda s, ub: jnp.concatenate([s[1:], ub[None]], axis=0), states,
-            u_b)
-
-        def body(carry, inp):
-            lam_, mu_ = carry
-            u_n, u_next, n = inp
-            t_n = t0 + dt * (a + n)
-            lam_, th_bar = _adjoint_step(f, cfg, u_n, u_next, theta_p, t_n,
-                                         dt, lam_)
-            return (lam_, tree_add(mu_, th_bar)), None
-
-        (lam, mu), _ = jax.lax.scan(
-            body, (lam, mu), (states, u_nexts, jnp.arange(m)), reverse=True)
-    return lam, mu
-
-
-_imp_revolve2.defvjp(_imp_revolve2_fwd, _imp_revolve2_bwd)
-
-
-# ---------------------------------------------------------------------------
-# pnode + spill: segment-batched host-callback checkpoint streaming.  The
-# residual is one token scalar + u_final, so compiled device-live memory is
-# O(segment) state vectors regardless of N_t.  vmap-compatible: the store's
-# batched callbacks ship the whole batch per round-trip (each element's
-# checkpoints occupy its own block of the slot) — the per-batch-element key
-# scheme that lets thousands of vmapped stiff systems train under one
-# memory budget.
-# ---------------------------------------------------------------------------
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6))
-def _imp_spill(f, cfg, t0, dt, n_steps, store, segment, u0, theta_p):
-    u_final, stats, _ = _imp_solve(f, cfg, t0, dt, n_steps, u0, theta_p,
-                                   save_states=False, obs=store._obs)
-    return u_final, stats
-
-
-@scope("imp_spill/fwd")
-def _imp_spill_fwd(f, cfg, t0, dt, n_steps, store, segment, u0, theta_p):
-    n_full, rem = divmod(n_steps, segment)
-    obs = store._obs
-    track_rescue = cfg.rescue is not None or cfg.fault is not None
-    # resilient mode keeps each segment's ENTRY state in the residuals
-    # (O(sqrt(N)) extra liveness) so the bwd sweep can re-integrate a
-    # segment whose spilled payload fails its integrity check
-    resilient = cfg.resilient
-
-    def run_segment(u, stats, tok, base, m):
-        def step(carry, i):
+        def body(carry, k):
             u, st = carry
-            t = t0 + dt * (base + i)
-            u_next, info, resc = _step(f, cfg, u, theta_p, t, dt, base + i)
-            return (u_next, _stats_merge(st, info, resc)), \
-                ((u, info) if obs is not None else u)
+            u, info, resc = self._step(u, theta, start, k)
+            return (u, st if st is None else _stats_merge(st, info, resc)), \
+                (info if self.obs is not None else None)
 
-        (u, stats), ys = jax.lax.scan(step, (u, stats), jnp.arange(m))
-        staged, infos = ys if obs is not None else (ys, None)
-        tok = store.write_batch(tok, base, staged)  # ONE callback, m slots
-        return u, stats, tok, infos
+        (u, aux), infos = jax.lax.scan(body, (u, aux), jnp.arange(m))
+        if self.obs is not None:
+            self._emit("implicit.recompute" if recompute else
+                       "implicit.steps", start, infos)
+        return u, aux
 
-    u, stats, tok = u0, _stats_zero(), store.init_token()
-    seg_infos = rem_infos = None
-    seg_starts = rem_start = None
-    if n_full:
-        def seg_body(carry, s_idx):
-            u, stats, tok = carry
-            u_in = u
-            u, stats, tok, infos = run_segment(u, stats, tok,
-                                               s_idx * segment, segment)
-            return (u, stats, tok), \
-                (infos, u_in if resilient else None)
+    def capture(self, u, theta, n):
+        return u, u
 
-        (u, stats, tok), (seg_infos, seg_starts) = jax.lax.scan(
-            seg_body, (u, stats, tok), jnp.arange(n_full))
-    if rem:
-        rem_start = u if resilient else None
-        u, stats, tok, rem_infos = run_segment(
-            u, stats, tok, jnp.asarray(n_full * segment), rem)
-    if obs is not None:
-        # stacked top-level taps (see _imp_solve: per-step taps inside
-        # the scans are dropped in custom_vjp fwd rules on jax 0.4.37)
+    def restart(self, ckpt):
+        return ckpt
+
+    def adjoint(self, ckpt, u_next, theta, n, lam):
+        return implicit_adjoint_step(
+            self.f, ckpt, u_next, theta, self.t0 + self.dt * n, self.dt,
+            self.cfg.theta, lam, self.cfg.gmres_iters, self.cfg.gmres_tol)
+
+    def recompute(self, u, theta, base, m):
+        def body(u, i):
+            return self._step(u, theta, base, i)[0], u
+
+        return jax.lax.scan(body, u, jnp.arange(m))[1]
+
+    def tap(self, seg_infos, rem_infos, rem_base, aux):
+        if self.obs is None:
+            return
         if seg_infos is not None:
-            flat = jtu.tree_map(
-                lambda a: a.reshape((-1,) + a.shape[2:]), seg_infos)
-            obs.emit("implicit.steps", base=jnp.asarray(0),
-                     iters=flat.iters, residual=flat.residual,
-                     converged=flat.converged)
+            self._emit("implicit.steps", 0, jtu.tree_map(
+                lambda a: a.reshape((-1,) + a.shape[2:]), seg_infos))
         if rem_infos is not None:
-            obs.emit("implicit.steps", base=jnp.asarray(n_full * segment),
-                     iters=rem_infos.iters, residual=rem_infos.residual,
-                     converged=rem_infos.converged)
-        if track_rescue:
-            obs.emit("implicit.rescue", base=jnp.asarray(0),
-                     rescued=stats.rescued)
-    return (u, stats), (tok, u, theta_p, seg_starts, rem_start)
-
-
-@scope("imp_spill/bwd")
-def _imp_spill_bwd(f, cfg, t0, dt, n_steps, store, segment, res, ct):
-    g, _ = ct
-    tok, u_final, theta_p, seg_starts, rem_start = res
-    n_full, rem = divmod(n_steps, segment)
-    obs = store._obs
-    resilient = cfg.resilient
-
-    def recompute_states(u_start, base, m):
-        # identical op sequence to the forward sub-sweep (same
-        # t0 + dt*(base+i) times, same _step — injected faults and their
-        # rescues re-fire, keyed by the absolute step index), so the
-        # recovered states are bitwise the ones the lost segment held
-        def step(u, i):
-            t = t0 + dt * (base + i)
-            u_next, _info, _resc = _step(f, cfg, u, theta_p, t, dt, base + i)
-            return u_next, u
-
-        _, states = jax.lax.scan(step, u_start, jnp.arange(m))
-        return states
-
-    def run_segment_bwd(lam, mu, u_next, tok, base, m, u_start):
-        if resilient:
-            tok, ok, fetched = store.prefetch_checked(tok, base, m)
-            states = jax.lax.cond(
-                ok, lambda _: fetched,
-                lambda _: recompute_states(u_start, base, m), None)
-            if obs is not None:  # bwd-rule emits survive jit(grad)
-                obs.emit("spill.recover", base=jnp.asarray(base), ok=ok)
-        else:
-            tok, states = store.prefetch(tok, base, m)  # ONE callback
-            # software-pipeline the NEXT (earlier) full segment: queue its
-            # background gather now so segment base-segment streams in
-            # while this segment's adjoint scan runs (no-op for tiers
-            # without an async path; resilient mode stays synchronous so
-            # checksum verification and fault injection keep their
-            # deterministic callback order)
-            nb = base - segment
-            tok = jax.lax.cond(
-                nb >= 0,
-                lambda t: store.prefetch_issue(t, jnp.maximum(nb, 0),
-                                               segment),
-                lambda t: t, tok)
-        u_nexts = jtu.tree_map(
-            lambda s, un: jnp.concatenate([s[1:], un[None]], axis=0), states,
-            u_next)
-
-        def step(carry, inp):
-            lam, mu = carry
-            u_n, u_np1, i = inp
-            t_n = t0 + dt * (base + i)
-            lam, th_bar = _adjoint_step(f, cfg, u_n, u_np1, theta_p, t_n, dt,
-                                        lam)
-            return (lam, tree_add(mu, th_bar)), None
-
-        (lam, mu), _ = jax.lax.scan(step, (lam, mu),
-                                    (states, u_nexts, jnp.arange(m)),
-                                    reverse=True)
-        # the next (earlier) segment's u_next is this segment's first state
-        u_prev = jtu.tree_map(lambda s: s[0], states)
-        return lam, mu, u_prev, tok
-
-    lam, mu, u_next = g, tree_zeros_like(theta_p), u_final
-    if rem:  # the trailing partial segment is adjointed first
-        lam, mu, u_next, tok = run_segment_bwd(
-            lam, mu, u_next, tok, jnp.asarray(n_full * segment), rem,
-            rem_start)
-    elif n_full and not resilient:
-        # no partial segment issued the first background gather — warm the
-        # pipeline for the last full segment before the scan consumes it
-        tok = store.prefetch_issue(tok, jnp.asarray((n_full - 1) * segment),
-                                   segment)
-    if n_full:
-        def seg_body(carry, inp):
-            s_idx, u_start = inp
-            lam, mu, u_next, tok = carry
-            lam, mu, u_next, tok = run_segment_bwd(lam, mu, u_next, tok,
-                                                   s_idx * segment, segment,
-                                                   u_start)
-            return (lam, mu, u_next, tok), None
-
-        (lam, mu, u_next, tok), _ = jax.lax.scan(
-            seg_body, (lam, mu, u_next, tok),
-            (jnp.arange(n_full), seg_starts), reverse=True)
-    return lam, mu
-
-
-_imp_spill.defvjp(_imp_spill_fwd, _imp_spill_bwd)
+            self._emit("implicit.steps", rem_base, rem_infos)
+        if self.cfg.rescue is not None or self.cfg.fault is not None:
+            self.obs.emit("implicit.rescue", base=jnp.asarray(0),
+                          rescued=aux.rescued)

@@ -146,7 +146,7 @@ def sweep_checkpoint_positions(n_t: int, n_c: int) -> List[int]:
 #   ("advance", start, n)   re-run n forward steps from `start`, keeping the
 #                           arrival state+stages as a new checkpoint
 #   ("adjoint", idx)        adjoint one step at index idx (state+stages held)
-# The executor in core/adjoint.py interprets these with traced values; this
+# The executor in core/sweeps.py interprets these with traced values; this
 # module only decides *what* to do (pure Python ints).
 
 

@@ -197,8 +197,8 @@ def spill_callback_counts(policy: str, n_steps: int, *,
             bwd += {"advance": 2, "adjoint": 2, "free": 1}[act[0]]
         return {"forward": fwd, "backward": bwd, "total": fwd + bwd}
     if policy == "revolve2":
-        from repro.core.adjoint import _segment_bounds
-        nb = len(_segment_bounds(n_steps, ncheck))
+        from repro.core.sweeps import segment_bounds
+        nb = len(segment_bounds(n_steps, ncheck))
         return {"forward": nb, "backward": 2 * nb, "total": 3 * nb}
     return {"forward": 0, "backward": 0, "total": 0}
 

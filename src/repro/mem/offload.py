@@ -1,8 +1,8 @@
 """Checkpoint stores: where adjoint checkpoints live between fwd and bwd.
 
-The revolve/pnode adjoints in ``core/adjoint.py`` write (state, stages)
-checkpoints through one of these stores instead of returning them directly
-as ``custom_vjp`` residuals.  Four tiers:
+The checkpointed sweeps in ``core/sweeps.py`` write their checkpoints
+(explicit: state and stages; implicit: the state) through one of these
+stores instead of returning them directly as ``custom_vjp`` residuals.  Four tiers:
 
   device   checkpoints stay traced values and travel through the residual
            pytree — exactly the seed behavior (XLA keeps them in device
@@ -177,13 +177,12 @@ grads (tests/test_mem.py, tests/test_longhaul.py).
 
 vmap: the *slot-addressed* mode is not supported under ``vmap`` (the
 callback sees one logical index for the whole batch, so per-example
-checkpoints would alias — ``core.adjoint._reject_vmap_offload`` catches it
+checkpoints would alias — ``core.sweeps.resolve_offload`` rejects it
 up front).  The *segment-batched* mode IS (``vmap_method="broadcast_all"``):
 one callback serves the entire batch, each slot stores the full batch
 block with batch axes leading, so element b's checkpoints occupy index b
-of the block — the per-batch-element layout the vmapped implicit
-ensembles rely on (``core.implicit``) and, since PR 10, the vmapped
-explicit scanned pnode path (``core.adjoint``).  Stores are
+of the block — the per-batch-element layout the vmapped segmented sweep
+relies on (``core.sweeps``, explicit and implicit alike).  Stores are
 per-``odeint``-call objects unless a caller passes its own
 (``odeint(offload_store=...)``), so concurrent solves never share keys
 (a caller-owned ``disk_dir`` likewise belongs to one live store at a
@@ -284,8 +283,8 @@ def batch_scale(tree: PyTree) -> int:
     factor by which vmap multiplies every callback payload.
 
     Must be called where the mapped axes are still visible as
-    ``BatchTracer``s (the ``odeint`` entry point, like
-    ``core.adjoint._reject_vmap_offload``): ``custom_vjp`` forwards are
+    ``BatchTracer``s (``core.sweeps.resolve_offload``, called by the entry
+    points): ``custom_vjp`` forwards are
     retraced at *logical* shapes, so by the time ``write_batch`` runs the
     batch axes cannot be recovered from its arguments."""
     def scale(x) -> int:

@@ -8,14 +8,8 @@ precision — they are exact reorderings of the same chain rule.  The
 per-step discrepancy is O(h^2) (Prop. 1), checked here by a dt-halving
 convergence sweep at fixed horizon (global gap O(h), per-step gap O(h^2)).
 
-The implicit family (theta-methods, §3.3) gets the same lockdown: for
-theta in {0.5 (cn), 1.0 (beuler)} the discrete adjoint of every implicit
-checkpoint policy must match AD through an unrolled dense-Jacobian Newton
-solve of the same scheme (the differentiable oracle — backprop through the
-production Newton/GMRES ``while_loop`` has no reverse rule), and within a
-policy the gradients must be **bitwise-identical across every offload
-tier** (device / host / spill), in both eager and jit execution — the
-store moves checkpoints, never changes a single arithmetic op.
+The implicit family gets the same lockdown in
+``test_reverse_accuracy_implicit.py`` and ``test_reverse_accuracy_tiers.py``.
 """
 import jax
 import jax.numpy as jnp
@@ -23,27 +17,9 @@ import numpy as np
 import pytest
 
 from repro.core.adjoint import POLICIES, odeint
-from repro.core.implicit import odeint_implicit
+from tests.reverse_problem import HORIZON, _problem, _vf
 
 jax.config.update("jax_enable_x64", True)
-
-D = 6
-HORIZON = 0.6
-
-
-def _vf():
-    def f(u, th, t):
-        return jnp.tanh(th["W"] @ u + th["b"]) - 0.2 * u \
-            + 0.05 * jnp.cos(t) * u
-    return f
-
-
-def _problem(seed=7):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    u0 = jax.random.normal(ks[0], (D,))
-    th = {"W": 0.4 * jax.random.normal(ks[1], (D, D)),
-          "b": 0.1 * jax.random.normal(ks[2], (D,))}
-    return u0, th
 
 
 def _grads(policy, *, method="rk4", n_steps=12, dt=HORIZON / 12, **kw):
@@ -97,111 +73,3 @@ def test_continuous_adjoint_o_h2_per_step():
         g_p = _grads("pnode", method="euler", n_steps=n, dt=HORIZON / n)
         g_n = _grads("naive", method="euler", n_steps=n, dt=HORIZON / n)
         assert _gap(g_p, g_n) < 1e-10
-
-
-# ---------------------------------------------------------------------------
-# implicit family (theta-methods): oracle accuracy + bitwise tier identity
-# ---------------------------------------------------------------------------
-
-N_IMP = 6
-DT_IMP = HORIZON / N_IMP
-_THETA_OF = {"cn": 0.5, "beuler": 1.0}
-
-#: (policy, ncheck, offload tiers that policy writes through)
-IMPLICIT_MATRIX = [
-    ("pnode", None, (None, "spill")),
-    ("revolve", 2, (None, "host", "spill")),
-    ("revolve2", 2, (None, "host", "spill")),
-]
-
-
-def _implicit_grads(method, policy="pnode", *, jit=False, **kw):
-    f = _vf()
-    u0, th = _problem()
-
-    def loss(u0_, th_):
-        uf = odeint_implicit(f, u0_, th_, dt=DT_IMP, n_steps=N_IMP,
-                             method=method, adjoint=policy, newton_iters=20,
-                             newton_tol=1e-13, gmres_tol=1e-13, **kw)
-        return jnp.sum(uf ** 2)
-
-    fn = jax.grad(loss, argnums=(0, 1))
-    return (jax.jit(fn) if jit else fn)(u0, th)
-
-
-def _oracle_implicit_grads(method):
-    """AD through an unrolled dense-Jacobian Newton solve of the identical
-    theta-scheme: the reverse-accuracy reference the production
-    matrix-free solver cannot provide itself."""
-    theta = _THETA_OF[method]
-    f = _vf()
-    u0, th = _problem()
-
-    def step(u, th_, t_n):
-        t_next = t_n + DT_IMP
-        g_const = u + DT_IMP * (1 - theta) * f(u, th_, t_n)
-        v = u + DT_IMP * f(u, th_, t_n)
-        for _ in range(25):
-            r = v - DT_IMP * theta * f(v, th_, t_next) - g_const
-            J = jnp.eye(D) - DT_IMP * theta * jax.jacfwd(
-                lambda uu: f(uu, th_, t_next))(v)
-            v = v - jnp.linalg.solve(J, r)
-        return v
-
-    def loss(u0_, th_):
-        u = u0_
-        for k in range(N_IMP):
-            u = step(u, th_, k * DT_IMP)
-        return jnp.sum(u ** 2)
-
-    return jax.grad(loss, argnums=(0, 1))(u0, th)
-
-
-def _assert_bitwise(g, g_ref, ctx=""):
-    for a, b in zip(jax.tree_util.tree_leaves(g),
-                    jax.tree_util.tree_leaves(g_ref)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=ctx)
-
-
-@pytest.mark.parametrize("method", ["cn", "beuler"])
-@pytest.mark.parametrize("policy,ncheck", [(p, k) for p, k, _ in
-                                           IMPLICIT_MATRIX])
-def test_implicit_policy_matches_ad_through_newton(method, policy, ncheck):
-    """Each implicit checkpoint policy reproduces AD-through-dense-Newton
-    to tight tolerance, for both theta points of the family."""
-    g_ref = _oracle_implicit_grads(method)
-    kw = {"ncheck": ncheck} if ncheck is not None else {}
-    g = _implicit_grads(method, policy, **kw)
-    for a, b in zip(jax.tree_util.tree_leaves(g),
-                    jax.tree_util.tree_leaves(g_ref)):
-        np.testing.assert_allclose(a, b, rtol=1e-7, atol=1e-9)
-
-
-@pytest.mark.parametrize("method", ["cn", "beuler"])
-@pytest.mark.parametrize("policy,ncheck,tiers",
-                         IMPLICIT_MATRIX,
-                         ids=[p for p, _, _ in IMPLICIT_MATRIX])
-def test_implicit_bitwise_across_offload_tiers(method, policy, ncheck,
-                                               tiers):
-    """Within a policy the offload tier must not change one bit of the
-    gradient — eager and jit each compared against their own device-tier
-    anchor (XLA fusion may round eager and jit differently, but tiers
-    within a mode run the identical op sequence)."""
-    kw = {"ncheck": ncheck} if ncheck is not None else {}
-    for jit in (False, True):
-        anchor = _implicit_grads(method, policy, jit=jit, **kw)
-        for tier in tiers[1:]:
-            g = _implicit_grads(method, policy, jit=jit, offload=tier, **kw)
-            _assert_bitwise(g, anchor,
-                            f"{method}/{policy}/offload={tier}/jit={jit}")
-
-
-def test_implicit_policies_bitwise_identical_under_jit():
-    """Under jit the checkpoint policies are not merely close — recompute
-    is bitwise-deterministic, so dense pnode, revolve and revolve2 agree
-    exactly (the implicit analogue of the explicit policy matrix)."""
-    anchor = _implicit_grads("cn", "pnode", jit=True)
-    for policy, ncheck, _ in IMPLICIT_MATRIX[1:]:
-        g = _implicit_grads("cn", policy, jit=True, ncheck=ncheck)
-        _assert_bitwise(g, anchor, f"cn/{policy} vs pnode under jit")
