@@ -5,9 +5,11 @@ loss-and-grad — the compiler's own statement of what must be resident.
 The paper's claims to reproduce:
   * NODE-naive grows ~N_t * N_s * N_l (steepest),
   * ACA / PNODE2 grow ~N_t (solutions only),
-  * PNODE grows ~N_t * (N_s+1) but with NO NN graph inside (shallow),
+  * PNODE grows ~N_t * (N_s+1) but with NO NN graph inside (shallow);
+    this implementation's PNODE stores states only, so it grows ~N_t,
   * NODE-cont is flat,
-  * slope(PNODE)/slope(naive) ~ (N_s+1)/(N_s*N_l-ish)  — big savings."""
+  * slope(PNODE)/slope(naive) ~ (N_s+1)/(N_s*N_l-ish)  — big savings
+    (~1/(N_s*N_l-ish) here)."""
 from __future__ import annotations
 
 import jax

@@ -13,9 +13,12 @@ baseline of the paper's Table 2 is implemented:
               recompute the whole forward and backprop through it.
   aca         ACA: checkpoint the state at every step; reverse pass
               re-executes each step under low-level AD (jax.vjp of the step).
-  pnode       the paper's method: checkpoint states AND stage values at every
-              step; reverse pass uses the high-level per-stage adjoint
-              (rk_adjoint_step) — no recomputation, graph depth O(N_l).
+  pnode       the paper's method: checkpoint at every step; reverse pass uses
+              the high-level per-stage adjoint (rk_adjoint_step) — no
+              recomputation, graph depth O(N_l).  The paper stores the
+              states AND stage values; here a checkpoint is the state, and
+              the reverse step takes each stage value from that stage's own
+              linearisation (``rk_linearize``), which it makes anyway.
   pnode2      PNODE2 variant: checkpoint solutions only; one step recompute
               per reverse step.
   revolve     PNODE with the binomial checkpointing schedule of Prop. 2
@@ -58,14 +61,13 @@ from repro.core.integrators import (
     PyTree,
     VectorField,
     rk_adjoint_step,
-    rk_combine,
+    rk_stage_read,
     rk_stages,
     rk_step,
     solve_fixed,
     tree_add,
     tree_scale,
     tree_stack,
-    tree_unstack,
     tree_zeros_like,
 )
 from repro.core.tableaus import get_tableau
@@ -212,11 +214,7 @@ def adjoint_stages(method: str) -> int:
     when b_i == 0 and no later stage depends on it (e.g. dopri5's 7th/FSAL
     stage), so NFE-B can be below N_s per step."""
     tab = get_tableau(method)
-    s = tab.num_stages
-    return sum(
-        1 for i in range(s)
-        if float(tab.b[i]) != 0.0
-        or any(float(tab.a[j, i]) != 0.0 for j in range(i + 1, s)))
+    return sum(rk_stage_read(tab, i) for i in range(tab.num_stages))
 
 
 def nfe_backward(method: str, n_steps: int, adjoint: str,
@@ -254,25 +252,25 @@ def nfe_backward(method: str, n_steps: int, adjoint: str,
 
 def checkpoint_floats(method: str, n_steps: int, adjoint: str, state_size: int,
                       ncheck: int | None = None) -> int:
-    """Analytic checkpoint storage (in state-vector units x state_size)."""
-    s = get_tableau(method).num_stages
+    """Analytic checkpoint storage (in state-vector units x state_size):
+    what the sweeps hold.  A pnode or revolve checkpoint is one state, not
+    the paper's state and N_s stages (Table 2's N_t (N_s+1) S for pnode):
+    the reverse step rebuilds the stages from their own linearisations."""
     if adjoint in ("naive", "continuous"):
         return 0
     if adjoint == "anode":
         return state_size
     if adjoint == "aca":
         return n_steps * state_size
-    if adjoint == "pnode":
-        return n_steps * (s + 1) * state_size
-    if adjoint == "pnode2":
+    if adjoint in ("pnode", "pnode2"):
         return n_steps * state_size
     if adjoint == "revolve":
-        return (ncheck + 1) * (s + 1) * state_size  # +1: segment boundary
+        return (ncheck + 1) * state_size  # +1: segment boundary
     if adjoint == "revolve2":
-        # boundary states + one in-flight segment of states+stages
+        # boundary states + one in-flight re-solved segment of states
         bounds = sweeps.segment_bounds(n_steps, ncheck)
         seg = max(b - a for a, b in bounds)
-        return (len(bounds) + seg * (s + 1)) * state_size
+        return (len(bounds) + seg) * state_size
     raise ValueError(adjoint)
 
 
@@ -340,8 +338,7 @@ def _odeint_cv_bwd(f, method, t0, dt, n_steps, policy, fused, res, g):
         states, theta = res  # states: pre-step states u_0..u_{N-1}, stacked
 
         def step_fn(u, th, t):
-            u_next, _ = rk_step(f, tab, u, th, t, dt)
-            return u_next
+            return rk_step(f, tab, u, th, t, dt)
 
         def body(carry, inp):
             lam, mu = carry
@@ -387,9 +384,9 @@ _odeint_cv.defvjp(_odeint_cv_fwd, _odeint_cv_bwd)
 @dataclasses.dataclass(frozen=True, eq=False)
 class RKStepper(sweeps.Stepper):
     """Explicit RK steps of size ``dt`` from ``t0``: a checkpoint is a
-    step's state and its stacked stages, so the adjoint step needs no f
-    evaluation to rebuild them and a revolve restart none to re-take the
-    step (``rk_combine``)."""
+    step's state, and the adjoint step rebuilds the step's stages from it
+    in forward order, each from its own linearisation (``rk_linearize``),
+    so it makes no f evaluation beyond the linearisations."""
 
     f: Callable
     t0: float
@@ -399,53 +396,38 @@ class RKStepper(sweeps.Stepper):
 
     scopes = {"dense": "adjoint", "revolve": "revolve",
               "revolve2": "revolve2", "segmented": "pnode_spill"}
-    ckpt_steps = 1
 
     @property
     def tab(self):
         return get_tableau(self.method)
 
-    def _rk(self, u, theta, t):
-        return rk_step(self.f, self.tab, u, theta, t, self.dt,
-                       fused=self.fused)
-
     def solve(self, u, theta, base, m, save=False, recompute=False):
         u, saved = solve_fixed(self.f, self.method, u, theta,
                                self.t0 + self.dt * base, self.dt, m,
-                               save_states=save, save_stages=save,
-                               fused=self.fused)
-        return u, None, (saved["states"], saved["stages"]) if save else None
+                               save_states=save, fused=self.fused)
+        return u, None, saved["states"] if save else None
 
     def step(self, u, aux, theta, base, i):
         n = base + i
         # t as solve_fixed forms it, so the states are bitwise dense pnode's
         t = self.t0 + n.astype(jnp.result_type(float)) * self.dt
-        u_next, stages = self._rk(u, theta, t)
-        return u_next, aux, (u, stages), None
+        u_next = rk_step(self.f, self.tab, u, theta, t, self.dt,
+                         fused=self.fused)
+        return u_next, aux, u, None
 
     def advance(self, u, aux, theta, start, m, recompute=False):
         if m <= 0:
             return u, aux
 
         def body(carry, k):
-            return self._rk(carry, theta, _t_of(self.t0, self.dt,
-                                                start + k))[0], None
+            return rk_step(self.f, self.tab, carry, theta,
+                           _t_of(self.t0, self.dt, start + k), self.dt,
+                           fused=self.fused), None
 
         return jax.lax.scan(body, u, jnp.arange(m))[0], aux
 
-    def capture(self, u, theta, n):
-        u_next, stages = self._rk(u, theta, _t_of(self.t0, self.dt, n))
-        return (u, stages), u_next
-
-    def restart(self, ckpt):
-        u, stages = ckpt
-        return rk_combine(self.tab, u, tree_unstack(stages,
-                                                     self.tab.num_stages),
-                          self.dt, fused=self.fused)
-
     def adjoint(self, ckpt, u_next, theta, n, lam):
-        u, stages = ckpt
-        return rk_adjoint_step(self.f, self.tab, u, stages, theta,
+        return rk_adjoint_step(self.f, self.tab, ckpt, None, theta,
                                _t_of(self.t0, self.dt, n), self.dt, lam,
                                fused=self.fused)
 

@@ -31,8 +31,9 @@ by the same sweeps (``repro.core.sweeps``, with ``ThetaStepper``):
             composes with ``jax.vmap``: one host round-trip per segment
             carries the whole batch, each element in its own block of the
             slot (see ``repro.mem.offload``).
-  revolve   the Prop. 2 schedule over states only (a slot costs S bytes,
-            not (N_s+1)S); segments are re-advanced by re-running Newton.
+  revolve   the Prop. 2 schedule over states (a slot costs S bytes, as an
+            explicit RK slot does); segments are re-advanced by re-running
+            Newton.
   revolve2  boundary states, each segment re-advanced once under a scan.
   auto      ``repro.mem.planner.plan_odeint`` picks (policy, ncheck,
             offload) under ``mem_budget=<bytes>`` with the implicit cost
@@ -385,8 +386,7 @@ def implicit_nfe_backward(n_steps: int, adjoint: str,
 def implicit_checkpoint_floats(n_steps: int, adjoint: str, state_size: int,
                                ncheck: int | None = None) -> int:
     """Checkpoint storage in floats: ONLY converged states are stored (the
-    Newton/GMRES iterates never enter the graph), so a slot costs S — not
-    the explicit family's (N_s+1)S."""
+    Newton/GMRES iterates never enter the graph), so a slot costs S."""
     if adjoint == "pnode":
         return (n_steps + 1) * state_size
     if adjoint == "revolve":
@@ -671,12 +671,6 @@ class ThetaStepper(sweeps.Stepper):
             self._emit("implicit.recompute" if recompute else
                        "implicit.steps", start, infos)
         return u, aux
-
-    def capture(self, u, theta, n):
-        return u, u
-
-    def restart(self, ckpt):
-        return ckpt
 
     def adjoint(self, ckpt, u_next, theta, n, lam):
         return implicit_adjoint_step(

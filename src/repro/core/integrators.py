@@ -6,13 +6,17 @@ The vector field signature everywhere in this framework is
 
 with ``u`` and ``theta`` arbitrary pytrees and ``t`` a scalar.
 
-``rk_step`` computes one step and returns the stage derivatives so that the
-high-level discrete adjoint (``core/adjoint.py``) can reconstruct stage
-inputs without re-evaluating ``f`` — this is the paper's "checkpoint the
-states *and stage values*" design (PNODE).  ``rk_adjoint_step`` implements
-the discrete adjoint recursion (eq. 7 of the paper, in the standard RK
-adjoint form of Hager/Sandu): one transposed JVP of ``f`` per stage, so the
-backpropagation graph depth is O(N_l), independent of N_t.
+``rk_step`` computes one step.  ``rk_adjoint_step`` implements the
+discrete adjoint recursion (eq. 7 of the paper, in the standard RK adjoint
+form of Hager/Sandu): one transposed JVP of ``f`` per stage, so the
+backpropagation graph depth is O(N_l), independent of N_t.  Each stage's
+linearisation evaluates f at the stage input, so its primal output is the
+stage derivative itself: from the step's state alone (``rk_linearize``)
+the adjoint step rebuilds every stage at the cost of the linearisations it
+makes anyway.  The checkpointed sweeps therefore store only states; the
+paper's PNODE stores the stage values as well.  The adaptive ring, which
+stores its accepted steps' stages, rebuilds the stage inputs from them
+(``rk_stage_inputs``).
 """
 from __future__ import annotations
 
@@ -151,12 +155,10 @@ def rk_combine(tab: ButcherTableau, u: PyTree, ks, h,
 
 
 def rk_step(f: VectorField, tab: ButcherTableau, u: PyTree, theta: PyTree,
-            t, h, fused: bool = False) -> Tuple[PyTree, PyTree]:
-    """One explicit RK step.  Returns (u_next, stages) with stages stacked
-    along a new leading axis of size N_s (so it scans cleanly)."""
-    ks = rk_stages(f, tab, u, theta, t, h, fused=fused)
-    u_next = rk_combine(tab, u, ks, h, fused=fused)
-    return u_next, tree_stack(ks)
+            t, h, fused: bool = False) -> PyTree:
+    """One explicit RK step: u_{n+1}."""
+    return rk_combine(tab, u, rk_stages(f, tab, u, theta, t, h, fused=fused),
+                      h, fused=fused)
 
 
 def rk_stage_inputs(tab: ButcherTableau, u: PyTree, stages: PyTree, h,
@@ -182,13 +184,42 @@ def _vf_at(f: VectorField, t):
     return f_t
 
 
+def rk_stage_read(tab: ButcherTableau, i: int) -> bool:
+    """Whether the discrete adjoint linearises stage i: it skips a stage
+    with b_i == 0 that no later stage reads (a_ji == 0 for j > i), such as
+    dopri5's FSAL stage."""
+    return float(tab.b[i]) != 0.0 or any(
+        float(tab.a[j, i]) != 0.0 for j in range(i + 1, tab.num_stages))
+
+
+def rk_linearize(f: VectorField, tab: ButcherTableau, u: PyTree,
+                 theta: PyTree, t, h, fused: bool = False) -> list:
+    """Linearise a step's stages in forward order from its state alone:
+    stage i's input x_i = u + h*sum_j a_ij k_j is built from the k_j
+    already in hand, and k_i is the primal output of x_i's own ``jax.vjp``,
+    so rebuilding the stages costs no f evaluation beyond the
+    linearisations.  Returns each stage's VJP function (None for a stage
+    ``rk_stage_read`` skips: no later stage reads its k_i either)."""
+    ks: list = [None] * tab.num_stages
+    vjps: list = [None] * tab.num_stages
+    for i in range(tab.num_stages):
+        if not rk_stage_read(tab, i):
+            continue
+        pairs = [(float(tab.a[i, j]), ks[j]) for j in range(i)
+                 if float(tab.a[i, j]) != 0.0]
+        xi = tree_stage_lincomb(u, pairs, scale=h, fused=fused)
+        ks[i], vjps[i] = jax.vjp(_vf_at(f, t + float(tab.c[i]) * h), xi,
+                                 theta)
+    return vjps
+
+
 def rk_adjoint_step(f: VectorField, tab: ButcherTableau, u: PyTree,
                     stages: PyTree, theta: PyTree, t, h,
                     lam: PyTree, fused: bool = False) -> Tuple[PyTree, PyTree]:
     """Discrete adjoint of one explicit RK step (the paper's eq. 7).
 
-    Given the step's initial state ``u``, its stored stage derivatives, and
-    the incoming adjoint ``lam`` (= lambda_{n+1}), returns
+    Given the step's initial state ``u`` and the incoming adjoint ``lam``
+    (= lambda_{n+1}), returns
 
         lam_prev  = (d u_{n+1} / d u_n)^T lam
         theta_bar = (d u_{n+1} / d theta)^T lam     (increment for mu)
@@ -198,25 +229,34 @@ def rk_adjoint_step(f: VectorField, tab: ButcherTableau, u: PyTree,
         (w_i, g_i) = vjp(f, x_i)(h * v_i)        # one transposed JVP per stage
         lam_prev = lam + sum_i w_i
         theta_bar = sum_i g_i
+
+    The stage linearisations come from the step's stored stage derivatives
+    (``stages``, stacked: x_i rebuilt by ``rk_stage_inputs``, each stage
+    linearised as the recursion reaches it) or, with ``stages=None``, from
+    ``rk_linearize`` on ``u`` alone.  Either way each linearised stage
+    costs one f evaluation.
     """
     s = tab.num_stages
-    xs = rk_stage_inputs(tab, u, stages, h, fused=fused)
+    if stages is None:
+        vjp_of = rk_linearize(f, tab, u, theta, t, h, fused=fused).__getitem__
+    else:
+        xs = rk_stage_inputs(tab, u, stages, h, fused=fused)
+
+        def vjp_of(i):
+            ti = t + float(tab.c[i]) * h
+            return jax.vjp(_vf_at(f, ti), xs[i], theta)[1]
+
     ws: list = [None] * s
     lam_prev = lam
     theta_bar = None
     for i in reversed(range(s)):
-        if float(tab.b[i]) == 0.0 and all(
-            float(tab.a[j, i]) == 0.0 for j in range(i + 1, s)
-        ):
-            ws[i] = None
+        if not rk_stage_read(tab, i):
             continue
         pairs = [(float(tab.a[j, i]), ws[j]) for j in range(i + 1, s)
                  if float(tab.a[j, i]) != 0.0 and ws[j] is not None]
         vi = tree_stage_lincomb(lam, pairs, base_coeff=float(tab.b[i]),
                                 fused=fused)
-        ti = t + float(tab.c[i]) * h
-        _, vjp_fn = jax.vjp(_vf_at(f, ti), xs[i], theta)
-        wi, gi = vjp_fn(tree_scale(h, vi))
+        wi, gi = vjp_of(i)(tree_scale(h, vi))
         ws[i] = wi
         lam_prev = tree_add(lam_prev, wi)
         theta_bar = gi if theta_bar is None else tree_add(theta_bar, gi)
@@ -232,26 +272,20 @@ def rk_adjoint_step(f: VectorField, tab: ButcherTableau, u: PyTree,
 def solve_fixed(f: VectorField, method: str, u0: PyTree, theta: PyTree,
                 t0: float, h: float, n_steps: int,
                 save_states: bool = False,
-                save_stages: bool = False,
                 fused: bool = False):
     """Integrate n_steps of size h with a fixed-step explicit RK method.
 
-    Returns (u_final, saved) where ``saved`` is a dict possibly containing
-    'states' (the N_t *pre-step* states u_0..u_{N_t-1}) and 'stages'
-    (N_t stacked stage pytrees).
+    Returns (u_final, saved) where ``saved`` is a dict holding 'states'
+    (the N_t *pre-step* states u_0..u_{N_t-1}, stacked) if
+    ``save_states``.
     """
     tab = get_tableau(method)
 
     def body(carry, n):
         u = carry
         t = t0 + n.astype(jnp.result_type(float)) * h
-        u_next, stages = rk_step(f, tab, u, theta, t, h, fused=fused)
-        out = {}
-        if save_states:
-            out["states"] = u
-        if save_stages:
-            out["stages"] = stages
-        return u_next, out
+        u_next = rk_step(f, tab, u, theta, t, h, fused=fused)
+        return u_next, {"states": u} if save_states else {}
 
     u_final, saved = jax.lax.scan(body, u0, jnp.arange(n_steps))
     return u_final, saved
@@ -266,7 +300,7 @@ def solve_fixed_trajectory(f: VectorField, method: str, u0: PyTree,
     def body(carry, n):
         u = carry
         t = t0 + n.astype(jnp.result_type(float)) * h
-        u_next, _ = rk_step(f, tab, u, theta, t, h)
+        u_next = rk_step(f, tab, u, theta, t, h)
         return u_next, u_next
 
     u_final, traj = jax.lax.scan(body, u0, jnp.arange(n_steps))

@@ -3,9 +3,12 @@
 Implements the checkpointing model of the paper (Zhang & Constantinescu,
 "Revolve-based adjoint checkpointing for multistage time integration"):
 
-* a checkpoint stores the step state AND the step's stage derivatives
-  (N_s + 1 vectors), so the adjoint of a checkpointed step needs no
-  recomputation at all;
+* a checkpoint lets the adjoint of the step starting there run with no
+  recomputation at all.  The paper's checkpoint stores the step state AND
+  the step's stage derivatives (N_s + 1 vectors); here it is the state
+  alone, since the adjoint step rebuilds each stage derivative as the
+  primal output of that stage's own linearisation
+  (``core.integrators.rk_linearize``), which it makes anyway;
 * during the *forward sweep* up to N_c checkpoints may be placed for free;
 * during the *reverse sweep*, freed slots are re-placed while re-advancing.
 
@@ -52,8 +55,8 @@ def prop2_optimal_extra_steps(n_t: int, n_c: int) -> int:
 # ---------------------------------------------------------------------------
 # exact DP
 #
-# REV(n, c): segment of n steps whose boundary checkpoint (state + stages of
-#   the segment's first step) is held; the forward sweep through the segment
+# REV(n, c): segment of n steps whose boundary checkpoint (the state of the
+#   segment's first step) is held; the forward sweep through the segment
 #   has already happened and placed nothing inside; c slots are free.
 #   Value = minimal extra forward steps to adjoint the whole segment.
 #
@@ -125,7 +128,7 @@ def optimal_extra_steps(n_t: int, n_c: int) -> int:
 
 def sweep_checkpoint_positions(n_t: int, n_c: int) -> List[int]:
     """Positions (step indices) at which the initial forward sweep stores
-    checkpoints (state + stages of the step starting there).  Position 0 is
+    checkpoints (the state of the step starting there).  Position 0 is
     the segment boundary and is always held implicitly."""
     pos: List[int] = []
     off, n, c = 0, n_t, n_c
@@ -144,8 +147,8 @@ def sweep_checkpoint_positions(n_t: int, n_c: int) -> List[int]:
 # The reverse executor works on segments between sweep checkpoints, right to
 # left.  Within a segment it follows the REV policy recursively.  Actions:
 #   ("advance", start, n)   re-run n forward steps from `start`, keeping the
-#                           arrival state+stages as a new checkpoint
-#   ("adjoint", idx)        adjoint one step at index idx (state+stages held)
+#                           arrival state as a new checkpoint
+#   ("adjoint", idx)        adjoint one step at index idx (its state held)
 # The executor in core/sweeps.py interprets these with traced values; this
 # module only decides *what* to do (pure Python ints).
 
@@ -156,7 +159,7 @@ def reverse_schedule(n_t: int, n_c: int) -> List[Tuple]:
     actions: List[Tuple] = []
 
     def rev_segment(start: int, n: int, c: int) -> None:
-        # boundary checkpoint at `start` is held (with stages)
+        # boundary checkpoint at `start` is held
         if n <= 0:
             return
         if n == 1:

@@ -4,18 +4,20 @@ theta-method (``core.implicit``) solves, plus the offload resolver their
 entry points and ``odeint_adaptive`` call.
 
 Placements (the reverse pass applies the stepper's per-step discrete
-adjoint to stored checkpoints; it never differentiates a step graph):
+adjoint to stored checkpoints; it never differentiates a step graph).  A
+checkpoint is one step's state, S bytes, for both steppers: the explicit
+RK adjoint step rebuilds the step's stages from their own linearisations.
 
-  dense       every checkpoint rides the custom_vjp residuals.
+  dense       every step's state rides the custom_vjp residuals.
   revolve     the binomial (Prop. 2) schedule, unrolled at trace time,
               through a slot-addressed ``CheckpointStore`` (device /
               pinned-host / callback spill): the recompute optimum.
   revolve2    two-level and scanned: the forward stores only the boundary
               states of the optimal sweep placement; the reverse re-advances
-              each segment once under ``lax.scan`` and adjoints it, so
-              compiled liveness is bounded on every backend.
+              each segment once under ``lax.scan``, saving its states, and
+              adjoints it, so compiled liveness is bounded on every backend.
   segmented   the scanned sweep through a spill/disk store: an inner scan
-              stages ``segment`` steps' checkpoints and ONE ``write_batch``
+              stages ``segment`` steps' states and ONE ``write_batch``
               callback ships them; ``reverse_segments`` reads them back.
               The residual is the store's token, so device-live memory is
               O(segment) checkpoints for any N_t, and the gradients are
@@ -199,9 +201,6 @@ class Stepper:
       step(u, aux, theta, base, i)  step ``base + i`` of a segment scan:
           ``(u_next, aux, checkpoint, info)``, ``info`` stacked for ``tap``
       advance(u, aux, theta, start, m, recompute=False) -> (u, aux)
-      capture(u, theta, n)  -> (checkpoint of step n, the state
-          ``ckpt_steps`` on)
-      restart(ckpt)         -> the state ``ckpt_steps`` after a checkpoint
       adjoint(ckpt, u_next, theta, n, lam) -> (lam_n, theta_bar)
       shifted(states, u_end) -> u_{n+1} beside each u_n (``needs_next``)
       recompute(u, theta, base, m) -> a segment's checkpoints, for a
@@ -210,7 +209,6 @@ class Stepper:
 
     scopes = {}
     needs_next = False  # the adjoint step reads u_{n+1}
-    ckpt_steps = 0      # steps a checkpoint's capture already advances
     resilient = False   # segmented reads are checked, with recompute
     obs = None
 
@@ -305,11 +303,10 @@ def revolve(st, n_steps, ncheck, store, u0, theta):
 
 def _revolve_fwd(st, n_steps, ncheck, store, u0, theta):
     with scope(f"{st.scopes['revolve']}/fwd"):
-        u, aux, k = u0, st.aux0(), st.ckpt_steps
+        u, aux = u0, st.aux0()
         for a, b in segment_bounds(n_steps, ncheck):
-            ckpt, u = st.capture(u, theta, a)
-            store.put(a, ckpt)
-            u, aux = st.advance(u, aux, theta, a + k, b - a - k)
+            store.put(a, u)
+            u, aux = st.advance(u, aux, theta, a, b - a)
         return st.out(u, aux), (store.pack(), u if st.needs_next else None,
                                 theta)
 
@@ -319,7 +316,7 @@ def _revolve_bwd(st, n_steps, ncheck, store, res, ct):
         ckpt_res, u_next, theta = res
         store.unpack(ckpt_res, [a for a, _ in segment_bounds(n_steps,
                                                              ncheck)])
-        lam, mu, k = st.cotangent(ct), tree_zeros_like(theta), st.ckpt_steps
+        lam, mu = st.cotangent(ct), tree_zeros_like(theta)
         # adjoints run in strictly decreasing step order, so u_{n+1} of the
         # step in hand is the previous adjoint's checkpoint (u_N first).
         # The schedule is unrolled at trace time: without the barrier XLA
@@ -327,10 +324,9 @@ def _revolve_bwd(st, n_steps, ncheck, store, res, ct):
         for act in revolve_mod.reverse_schedule(n_steps, ncheck):
             if act[0] == "advance":
                 _, start, m = act
-                u = st.restart(store.get(start))
-                u, _ = st.advance(u, None, theta, start + k, m - k,
+                u, _ = st.advance(store.get(start), None, theta, start, m,
                                   recompute=True)
-                store.put(start + m, st.capture(u, theta, start + m)[0])
+                store.put(start + m, u)
             elif act[0] == "adjoint":
                 _, idx = act
                 ckpt = store.get(idx)

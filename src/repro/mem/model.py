@@ -9,15 +9,21 @@ state size, and — for revolve — N_c (checkpoint slots):
   continuous  0                                    N_s * N_t   (not rev-acc)
   anode       N_t * N_s * A_f    (recompute+AD)    2 N_s N_t
   aca         N_t * S                              2 N_s N_t
-  pnode       N_t * (N_s+1) * S                    N_s^a N_t
+  pnode       N_t * S                              N_s^a N_t
   pnode2      N_t * S                              (N_s + N_s^a) N_t
-  revolve     (N_c+1) * (N_s+1) * S                N_s p~(N_t,N_c) + N_s^a N_t
-  revolve2    (N_c+1+seg*(N_s+1)) * S              ~N_s (N_t-N_c) + N_s^a N_t
+  revolve     (N_c+1) * S                          N_s p~(N_t,N_c) + N_s^a N_t
+  revolve2    (N_c+1+seg) * S                      ~N_s (N_t-N_c) + N_s^a N_t
 
 with S = state bytes, N_s^a = stages the discrete adjoint linearizes
 (``adjoint_stages``), p~ the Prop-2 recompute optimum, and A_f the bytes of
 AD residuals one f evaluation leaves behind (``f_activation_bytes`` — the
 N_l-dependent term that makes NODE-naive the steepest curve in Fig. 3).
+The rows price what the sweeps hold.  The paper's Table 2 gives pnode
+N_t (N_s+1) S, and revolve (N_c+1)(N_s+1) S, because its checkpoint holds
+the step's stage derivatives too; here a checkpoint is the state alone,
+since the reverse step takes each stage derivative from the primal output
+of that stage's linearisation, which it makes anyway
+(``core.integrators.rk_linearize``), at no extra f evaluation.
 An ``offload`` tier moves the ckpt-storage term off device (see
 ``repro.mem.offload``); it never changes NFE-B.
 
@@ -84,12 +90,10 @@ CALLBACK_LATENCY_S = 50e-6
 
 
 def slot_bytes(method: str, state_bytes: int) -> int:
-    """Bytes of ONE checkpoint slot: (N_s+1)*S for explicit tableaus
-    (state + staged k_i), S for implicit methods (converged states only).
-    The unit of the ``snaps_in_ram`` RAM/disk split."""
-    if is_implicit_method(method):
-        return int(state_bytes)
-    return (get_tableau(method).num_stages + 1) * int(state_bytes)
+    """Bytes of ONE checkpoint slot: S, the state, for explicit tableaus
+    and implicit methods alike.  The unit of the ``snaps_in_ram`` RAM/disk
+    split."""
+    return int(state_bytes)
 
 
 def _offload_io(offload: Optional[str], ckpt_bytes: int, callbacks: int,
@@ -292,10 +296,10 @@ def policy_cost(policy: str, *, method: str, n_steps: int, state_bytes: int,
                                           segment=segment)["total"]
         if policy == "pnode":
             # segment staging buffer: the batched sweeps hold one segment
-            # of (state, stages) checkpoints on device between callbacks
+            # of state checkpoints on device between callbacks
             from repro.mem.offload import default_segment
             seg = min(segment or default_segment(n_steps), n_steps)
-            work += seg * (s + 1) * state_bytes
+            work += seg * state_bytes
     ram, disk, io = _offload_io(offload, int(ckpt), callbacks, method,
                                 state_bytes, snaps_in_ram)
     return CostEstimate(policy=policy, ncheck=ncheck, offload=offload,
@@ -311,18 +315,15 @@ def max_fitting_ncheck(budget: int, *, method: str, n_steps: int,
                        newton_iters: int = 10,
                        gmres_iters: int = 20) -> Optional[int]:
     """Largest N_c whose revolve checkpoint set fits the byte budget
-    (Table-2 storage (N_c+1)(N_s+1)S explicit, (N_c+1)S implicit — only
-    converged states are stored), clamped to the valid [1, N_t-1] range;
-    None if even N_c = 1 does not fit."""
+    (storage (N_c+1)S: a slot is one state, explicit and implicit alike),
+    clamped to the valid [1, N_t-1] range; None if even N_c = 1 does not
+    fit."""
     probe = policy_cost("revolve", method=method, n_steps=n_steps,
                         state_bytes=state_bytes, theta_bytes=theta_bytes,
                         ncheck=1, newton_iters=newton_iters,
                         gmres_iters=gmres_iters)
     avail = budget - probe.work_bytes
-    if is_implicit_method(method):
-        per_slot = state_bytes
-    else:
-        per_slot = (get_tableau(method).num_stages + 1) * state_bytes
+    per_slot = slot_bytes(method, state_bytes)
     if per_slot <= 0:
         return n_steps - 1
     k = avail // per_slot - 1

@@ -1,8 +1,7 @@
 """Checkpoint stores: where adjoint checkpoints live between fwd and bwd.
 
 The checkpointed sweeps in ``core/sweeps.py`` write their checkpoints
-(explicit: state and stages; implicit: the state) through one of these
-stores instead of returning them directly as ``custom_vjp`` residuals.  Four tiers:
+through one of these stores instead of returning them directly as ``custom_vjp`` residuals.  Four tiers:
 
   device   checkpoints stay traced values and travel through the residual
            pytree — exactly the seed behavior (XLA keeps them in device
@@ -170,9 +169,14 @@ writes and reads, the bytes the store copied (``host_copy_bytes``) —
 recorded purely host-side inside the callbacks that already run, so the
 traced program is unchanged and grads stay bitwise identical with obs on.
 
+A checkpoint slot holds one step's state, S bytes, for the explicit RK
+and the implicit theta-method sweeps alike (the paper's explicit slot also
+holds the step's N_s stage derivatives; the explicit reverse step
+rebuilds those from their own linearisations, ``core.integrators``).
+
 Table-2 mapping (see ``repro.mem.model``): the store only changes WHERE
-N_c*(N_s+1) checkpoint vectors live, never how many f-evaluations the
-policy performs — spill and disk grads are bitwise-identical to device
+the checkpoint slots live, never how many f-evaluations the policy
+performs — spill and disk grads are bitwise-identical to device
 grads (tests/test_mem.py, tests/test_longhaul.py).
 
 vmap: the *slot-addressed* mode is not supported under ``vmap`` (the

@@ -8,7 +8,7 @@ cheapest one whose peak bytes fit:
   >  aca  >  [nothing fits on device]  pnode + spill offload
 
 For revolve the planner picks the *largest* N_c whose checkpoint set
-(N_c+1)(N_s+1)S fits — by Prop. 2 that minimizes recomputation, so a larger
+(N_c+1)S fits — by Prop. 2 that minimizes recomputation, so a larger
 budget can never cost more f evaluations (monotonicity; tested).  The spill
 tier is a last resort: it keeps NFE-B at pnode's optimum but pays PCIe/host
 traffic the NFE metric does not see, so it never outranks an in-device

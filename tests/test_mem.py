@@ -201,6 +201,46 @@ def test_pnode_spill_bitwise_over_consecutive_steps():
         _assert_bitwise(g, ref(u0, th))
 
 
+@pytest.mark.parametrize("method", ["rk4", "dopri5"])
+def test_pnode_spill_moves_one_state_per_step(method):
+    """A spilled pnode slot is the step's state, S bytes: the reverse step
+    rebuilds the stages from their own linearisations (dopri5's FSAL
+    stage, b_7 = 0, is neither stored nor rebuilt), so a gradient writes
+    and reads N_t * S bytes, not N_t * (N_s + 1) * S."""
+    u0, _ = _problem()
+    offload_mod.reset_spill_stats()
+    g = _grads("pnode", method=method, offload="spill", offload_segment=5)
+    st = offload_mod.spill_stats()
+    assert st["write_slots"] == st["read_slots"] == N_STEPS
+    assert st["write_bytes"] == st["read_bytes"] == N_STEPS * tree_bytes(u0)
+    _assert_bitwise(g, _grads("pnode", method=method))
+
+
+@pytest.mark.parametrize("policy", ["revolve", "revolve2"])
+def test_revolve_spill_slots_hold_one_state(policy):
+    """Revolve's slots and revolve2's boundary slots hold a state each."""
+    u0, _ = _problem()
+    offload_mod.reset_spill_stats()
+    _grads(policy, offload="spill", ncheck=3)
+    st = offload_mod.spill_stats()
+    assert st["write_slots"] > 0 and st["read_slots"] > 0
+    assert st["write_bytes"] == st["write_slots"] * tree_bytes(u0)
+    assert st["read_bytes"] == st["read_slots"] * tree_bytes(u0)
+
+
+@pytest.mark.parametrize("method", ["rk4", "dopri5"])
+def test_dense_pnode_residuals_hold_no_stages(method):
+    """The dense pnode custom_vjp keeps theta and N_t states, no stacked
+    stages: its residuals are N_t * S bytes beside theta's."""
+    f = _vf()
+    u0, th = _problem()
+    _, vjp_fn = jax.vjp(
+        lambda u, t: odeint(f, u, t, dt=DT, n_steps=N_STEPS, method=method,
+                            adjoint="pnode"), u0, th)
+    assert tree_bytes(jax.tree_util.tree_leaves(vjp_fn)) == \
+        N_STEPS * tree_bytes(u0) + tree_bytes(th)
+
+
 def _lanes(keys):
     # one vmapped write and read of 3 slots per lane under lane keys
     st = offload_mod.make_store("spill")

@@ -81,7 +81,7 @@ def test_disk_tier_actually_hits_disk():
 
 
 def test_split_caps_ram_resident_bytes():
-    # slot = (stages+1)*state for rk4; 3 slots in RAM, the rest on disk.
+    # slot = the state; 3 slots in RAM, the rest on disk.
     # routing is whole-batch: the segment must fit the RAM cap for any
     # batch to stay resident, so use segment=2 < snaps_in_ram=3
     cap = 3 * slot_bytes("rk4", U0.size * U0.dtype.itemsize)

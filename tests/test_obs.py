@@ -512,9 +512,9 @@ SPILL_SPANS = {f"obs:spill/{n}" for n in
 def test_spill_callbacks_open_one_span_each(policy, one_slot_per_callback,
                                             want, tmp_path, monkeypatch):
     u0, theta = _problem()
-    if one_slot_per_callback:   # the largest leaf of a slot: 4 stages x D
+    if one_slot_per_callback:   # a slot is the state: D floats
         monkeypatch.setattr(offload, "_CB_PAYLOAD_CAP",
-                            4 * D * u0.dtype.itemsize)
+                            D * u0.dtype.itemsize)
     kw = ({"ncheck": 2} if policy == "revolve"
           else {"offload_segment": 3})
 
